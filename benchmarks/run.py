@@ -153,8 +153,9 @@ def bench_dispatch_latency() -> None:
 
 
 def bench_kernels() -> None:
-    """Interpret-mode wall time (CPU) per kernel + analytic work terms —
-    the TPU perf story lives in EXPERIMENTS.md SS Roofline, not here."""
+    """Interpret-mode wall time (CPU) per kernel + analytic work terms.
+    These time the Pallas interpreter, not a chip: ``chip_smoke.py`` runs
+    the compiled kernels on a TPU."""
     import jax
     import jax.numpy as jnp
     from repro.kernels.decode_attention import decode_attention
@@ -176,7 +177,8 @@ def bench_kernels() -> None:
     da = jax.jit(lambda q, k, v, m: decode_attention(q, k, v, m,
                                                      interpret=True,
                                                      block_k=128))
-    (_, us) = _timed(lambda: jax.block_until_ready(da(qd, k, v, mask)),
+    kc, vc = jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2)  # cache layout
+    (_, us) = _timed(lambda: jax.block_until_ready(da(qd, kc, vc, mask)),
                      reps=3)
     bytes_ = 2 * B * KV * S * D * 4
     _print("kernel_decode_attention_interp", us, f"kv_bytes={bytes_:.2e}")

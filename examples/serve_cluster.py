@@ -21,8 +21,8 @@ from repro.core.profiling import NodeProfile, ProfilingTable
 from repro.core.requests import InferenceRequest
 from repro.core.resource_manager import Event, GatewayNode
 from repro.core.variants import VariantPool
-from repro.models import init_params
-from repro.serving.engine import BatchScheduler, Engine, EngineConfig
+from repro.serving.engine import (BatchScheduler, Engine, EngineConfig,
+                                  init_params_on)
 
 
 def main():
@@ -49,7 +49,9 @@ def main():
         key = (node, level)
         if key not in engines:
             vcfg = pool[level].config
-            params = init_params(vcfg, jax.random.PRNGKey(hash(key) % 2**31))
+            # bf16 serving weights, drawn on the device; one seed per level
+            params = init_params_on(vcfg, jax.random.PRNGKey(level),
+                                    jax.devices()[0])
             engines[key] = Engine(vcfg, params, EngineConfig(max_len=48))
         return engines[key]
 

@@ -3,14 +3,11 @@
 The paper's testbed is {Odroid XU4 x2, Jetson Nano, Raspberry Pi4}. Here a
 *node* is a TPU worker group (sub-mesh slice) with a chip count and a
 capability derate (thermal throttle / older generation — the DVFS-under-TDP
-analogue). Two backends execute a Dispatch:
-
-  * ``SimBackend``   — analytic makespan from the profiling table (+ optional
-    noise / straggler events). Used by benchmarks reproducing the paper's
-    figures, where ground truth == table entries, as in the paper's own
-    model-based evaluation.
-  * ``JaxBackend``   — really runs the variant configs on CPU-scaled models
-    (see serving engine); used by examples/serve_cluster.py and tests.
+analogue). ``SimBackend`` executes a Dispatch analytically: makespan from the
+profiling table (+ optional noise / straggler events), as in the paper's own
+model-based evaluation. Real engines serve a dispatch's shares through
+``repro.launch.serve.ShareRunner``; their timings do not feed back into the
+gateway yet.
 """
 from __future__ import annotations
 

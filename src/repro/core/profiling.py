@@ -31,7 +31,10 @@ import numpy as np
 
 from repro.configs import ModelConfig
 from repro.core.variants import VariantPool
-from repro.roofline.analysis import HBM_BW, PEAK_FLOPS
+from repro.roofline.analysis import V5E, chip_peaks
+
+# the analytic nodes are v5e slices
+_V5E = chip_peaks(V5E)
 
 # The serving batch the pre-batching cost model silently assumed; the
 # scalar ``ProfilingTable.perf`` matrix is the batch curve evaluated
@@ -96,8 +99,8 @@ def throughput_from_cost(cost: Dict[str, float], chips: int,
                          capability: float) -> float:
     """Roofline items/s from a precomputed per-item cost — the cost is
     per *variant*, so table builds hoist it out of the per-node loop."""
-    t_compute = cost["flops"] / (PEAK_FLOPS * chips * capability)
-    t_memory = cost["bytes"] / (HBM_BW * chips * capability)
+    t_compute = cost["flops"] / (_V5E.flops * chips * capability)
+    t_memory = cost["bytes"] / (_V5E.hbm_bw * chips * capability)
     return 1.0 / max(t_compute, t_memory)
 
 

@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -82,7 +80,7 @@ def _kernel(off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
         denom = jnp.maximum(l_ref[...], 1e-30)
         o_ref[0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
         # log-sum-exp per row — the bwd kernels recompute p from it
-        lse_ref[0, 0] = (m_ref[...] + jnp.log(denom))[:, 0]
+        lse_ref[0, 0] = m_ref[...] + jnp.log(denom)
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -92,8 +90,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     q_offset=None, return_lse: bool = False,
                     interpret: bool = False):
     """q: (B, H, Sq, D); k/v: (B, KV, S, D). Returns (B, H, Sq, D)
-    (+ the per-row log-sum-exp (B, H, Sq) when ``return_lse`` — the
-    backward kernels consume it).
+    (+ the per-row log-sum-exp (B, H, Sq, 1) when ``return_lse`` — the
+    backward kernels consume it; the trailing unit dim keeps its block
+    (block_q, 1) legal for Mosaic, which a (1, block_q) row over H is not).
 
     ``q_offset``: global position of q row 0 — lets a shard_map caller
     sequence-shard the query grid (each shard passes its own offset) while
@@ -129,18 +128,19 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         out_specs=[
             pl.BlockSpec((1, 1, block_q, d),
                          lambda b_, h_, i, j: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b_, h_, i, j: (b_, h_, i)),
+            pl.BlockSpec((1, 1, block_q, 1),
+                         lambda b_, h_, i, j: (b_, h_, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((b, h, sq), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, d), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -29,8 +29,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -58,7 +56,7 @@ def _p_and_mask(q, k, lse, rows, cols, *, scale, causal, window, softcap,
         mask &= cols <= rows
     if window is not None:
         mask &= cols > rows - window
-    p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
+    p = jnp.where(mask, jnp.exp(s - lse), 0.0)
     return p, dcap, mask
 
 
@@ -91,7 +89,7 @@ def _dq_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                                  softcap=softcap, seq_len=seq_len)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale
+        ds = p * (dp - delta) * scale
         if dcap is not None:
             ds = ds * dcap
         dq_acc[...] += jax.lax.dot_general(
@@ -137,7 +135,7 @@ def _dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale
+        ds = p * (dp - delta) * scale
         if dcap is not None:
             ds = ds * dcap
         dk_acc[...] += jax.lax.dot_general(
@@ -155,7 +153,7 @@ def flash_attention_bwd(q, k, v, dout, lse, delta, *,
                         softcap: float = 0.0, scale: Optional[float] = None,
                         block_q: int = 128, block_k: int = 512,
                         q_offset=None, interpret: bool = False):
-    """q/dout: (B,H,Sq,D); k/v: (B,KV,S,D); lse/delta: (B,H,Sq).
+    """q/dout: (B,H,Sq,D); k/v: (B,KV,S,D); lse/delta: (B,H,Sq,1).
     Returns (dq, dk, dv) with dk/dv group-summed to (B,KV,S,D)."""
     b, h, sq, d = q.shape
     kv = k.shape[1]
@@ -184,14 +182,16 @@ def flash_attention_bwd(q, k, v, dout, lse, delta, *,
             pl.BlockSpec((1, 1, block_k, d), lambda b_, h_, i, j: (b_, h_ // g, j, 0)),
             pl.BlockSpec((1, 1, block_k, d), lambda b_, h_, i, j: (b_, h_ // g, j, 0)),
             pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, i, j: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b_, h_, i, j: (b_, h_, i)),
-            pl.BlockSpec((1, 1, block_q), lambda b_, h_, i, j: (b_, h_, i)),
+            pl.BlockSpec((1, 1, block_q, 1),
+                         lambda b_, h_, i, j: (b_, h_, i, 0)),
+            pl.BlockSpec((1, 1, block_q, 1),
+                         lambda b_, h_, i, j: (b_, h_, i, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, d),
                                lambda b_, h_, i, j: (b_, h_, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -210,12 +210,12 @@ def flash_attention_bwd(q, k, v, dout, lse, delta, *,
             pl.BlockSpec((1, 1, block_q, d),
                          lambda b_, kv_, j, gq: (b_, kv_ * g + gq // nq,
                                                  gq % nq, 0)),
-            pl.BlockSpec((1, 1, block_q),
+            pl.BlockSpec((1, 1, block_q, 1),
                          lambda b_, kv_, j, gq: (b_, kv_ * g + gq // nq,
-                                                 gq % nq)),
-            pl.BlockSpec((1, 1, block_q),
+                                                 gq % nq, 0)),
+            pl.BlockSpec((1, 1, block_q, 1),
                          lambda b_, kv_, j, gq: (b_, kv_ * g + gq // nq,
-                                                 gq % nq)),
+                                                 gq % nq, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_k, d), lambda b_, kv_, j, gq: (b_, kv_, j, 0)),
@@ -229,7 +229,7 @@ def flash_attention_bwd(q, k, v, dout, lse, delta, *,
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -1,9 +1,10 @@
 """jit'd wrappers dispatching model-layout calls onto the Pallas kernels.
 
-On CPU (this container) the kernels execute in interpret mode — the kernel
-body runs as JAX ops for correctness validation; on TPU they compile to
-Mosaic. ``force_ref()`` routes everything to the pure-jnp oracles instead
-(used by tests to cross-check the dispatch layer itself).
+On TPU the kernels compile to Mosaic. On the CPU they run in interpret
+mode — the kernel body runs as JAX ops, which is how tests check them
+against the oracles. Any other backend is an error, never a silent
+interpreter. ``force_ref()`` routes everything to the pure-jnp oracles
+instead (used by tests to cross-check the dispatch layer itself).
 
 When sharding rules are active (``repro.distributed.ctx``), the kernels run
 under ``shard_map``: batch shards over (pod, data); the flash query grid
@@ -39,7 +40,11 @@ def force_ref(on: bool = True):
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(f"Pallas TPU kernels cannot run on {backend!r}: "
+                           "they compile on 'tpu' and interpret on 'cpu'")
+    return backend == "cpu"
 
 
 def _shard_axes(mesh, size: int, cands) -> Tuple[str, ...]:
@@ -83,7 +88,7 @@ def _flash_fwd_call(qt, kt, vt, window, softcap, scale):
         in_specs=(P(bspec, None, sspec, None),
                   P(bspec, None, None, None),
                   P(bspec, None, None, None)),
-        out_specs=(P(bspec, None, sspec, None), P(bspec, None, sspec)),
+        out_specs=(P(bspec, None, sspec, None), P(bspec, None, sspec, None)),
         check_vma=False)(qt, kt, vt)
 
 
@@ -114,8 +119,8 @@ def _flash_bwd_call(qt, kt, vt, dout, lse, delta, window, softcap, scale):
                   P(bspec, None, None, None),
                   P(bspec, None, None, None),
                   P(bspec, None, sspec, None),
-                  P(bspec, None, sspec),
-                  P(bspec, None, sspec)),
+                  P(bspec, None, sspec, None),
+                  P(bspec, None, sspec, None)),
         out_specs=(P(bspec, None, sspec, None),
                    P(bspec, None, None, None),
                    P(bspec, None, None, None)),
@@ -136,7 +141,7 @@ def _flash_vjp_fwd(qt, kt, vt, window, softcap, scale):
 def _flash_vjp_bwd(window, softcap, scale, res, dout):
     qt, kt, vt, out, lse = res
     delta = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1)
+                    axis=-1, keepdims=True)
     dq, dk, dv = _flash_bwd_call(qt, kt, vt, dout, lse, delta,
                                  window, softcap, scale)
     return dq, dk, dv

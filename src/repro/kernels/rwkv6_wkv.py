@@ -16,8 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, sout_ref, s_ref, *,
             chunk: int):
@@ -32,7 +30,7 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, sout_ref, s_ref, *,
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)                   # (chunk, Dv)
     w = w_ref[0].astype(jnp.float32)                   # (chunk, Dk)
-    u = u_ref[...]                                     # (1, Dk)
+    u = u_ref[0]                                       # (1, Dk)
 
     def step(t, carry):
         s, ys = carry                                  # s: (Dk, Dv)
@@ -72,7 +70,7 @@ def rwkv6_wkv(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
             pl.BlockSpec((1, chunk, dk), lambda b_, j: (b_, j, 0)),
             pl.BlockSpec((1, chunk, dv), lambda b_, j: (b_, j, 0)),
             pl.BlockSpec((1, chunk, dk), lambda b_, j: (b_, j, 0)),
-            pl.BlockSpec((1, dk), lambda b_, j: (b_, 0)),
+            pl.BlockSpec((1, 1, dk), lambda b_, j: (b_, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, chunk, dv), lambda b_, j: (b_, j, 0)),
@@ -83,7 +81,7 @@ def rwkv6_wkv(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
             jax.ShapeDtypeStruct((bh, dk, dv), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(r, k, v, w, u)
+    )(r, k, v, w, u.reshape(bh, 1, dk))

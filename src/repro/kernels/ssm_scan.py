@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 
 def _kernel(u_ref, dt_ref, b_ref, c_ref, a_ref, d_ref, y_ref, hout_ref,
             h_ref, *, chunk: int):
@@ -92,7 +90,7 @@ def ssm_scan(u: jax.Array, dt: jax.Array, bm: jax.Array, cm: jax.Array,
             jax.ShapeDtypeStruct((b, d_in, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((block_d, n), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(u, dt, bm, cm, a, d_skip.reshape(1, -1))

@@ -320,6 +320,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
         coll_scales = {}
         coll_default = 0.5
 
+    v5e = roofline.chip_peaks(roofline.V5E)
     hbm = raw["bytes"] * mem_scale
     wire = sum(v * coll_scales.get(k, coll_default)
                for k, v in raw["coll_wire"].items())
@@ -332,9 +333,9 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
         "flops_per_dev": raw["flops"],
         "hbm_bytes_per_dev": hbm,
         "collective_wire_bytes": wire,
-        "compute_s": raw["flops"] / roofline.PEAK_FLOPS,
-        "memory_s": hbm / roofline.HBM_BW,
-        "collective_s": wire / roofline.ICI_BW,
+        "compute_s": raw["flops"] / v5e.flops,
+        "memory_s": hbm / v5e.hbm_bw,
+        "collective_s": wire / v5e.ici_bw,
         "model_flops_per_dev": mf,
         "collective_counts": raw["coll_counts"],
         "collective_wire_by_kind": raw["coll_wire"],
@@ -347,7 +348,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
     rec["dominant"] = max(terms, key=terms.get)
     rec["useful_flops_ratio"] = mf / raw["flops"] if raw["flops"] else 0.0
     bound = max(terms.values())
-    rec["roofline_fraction"] = (mf / roofline.PEAK_FLOPS) / bound if bound else 0.0
+    rec["roofline_fraction"] = (mf / v5e.flops) / bound if bound else 0.0
 
     if verbose:
         print(f"== {arch} x {shape_name} [{rec['mesh']}, {rules.name}, "

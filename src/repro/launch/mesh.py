@@ -10,36 +10,28 @@ from __future__ import annotations
 import jax
 
 
-def _auto_kw(n: int) -> dict:
-    """axis_types=Auto when this jax has AxisType (>= 0.5); older
-    releases (e.g. 0.4.x) predate explicit axis types and every
-    make_mesh axis is implicitly Auto already — pass nothing."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
+def _make_mesh(shape, axes) -> jax.sharding.Mesh:
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_auto_kw(len(axes)))
+    return _make_mesh(shape, axes)
 
 
-def make_abstract_mesh(shape, axes) -> "jax.sharding.AbstractMesh":
-    """Version-portable AbstractMesh: jax >= 0.5 takes
-    ``AbstractMesh(axis_sizes, axis_names)``, while 0.4.x wants one
-    ``((name, size), ...)`` shape tuple. Lets the 16x16 sharding rules
-    be unit-tested on a 1-CPU box under either signature."""
-    try:
-        return jax.sharding.AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))
+def make_abstract_mesh(shape, axes) -> jax.sharding.AbstractMesh:
+    """Device-free mesh: lets the 16x16 sharding rules be unit-tested on a
+    1-CPU box."""
+    return jax.sharding.AbstractMesh(tuple(shape), tuple(axes))
 
 
 def make_local_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:
-    """Small mesh over whatever devices exist (tests / CPU smoke)."""
+    """Small (data, model) mesh over this host's devices. Asking for more
+    devices than exist is an error, not a smaller mesh."""
     n = jax.device_count()
     if data * model > n:
-        data, model = n, 1
-    return jax.make_mesh((data, model), ("data", "model"), **_auto_kw(2))
+        raise ValueError(f"a {data}x{model} mesh needs {data * model} "
+                         f"devices; {n} exist")
+    return _make_mesh((data, model), ("data", "model"))

@@ -4,32 +4,45 @@ profiling, Gateway dispatch (Algorithm 1), accuracy-configured variants.
   PYTHONPATH=src python -m repro.launch.serve --arch phi4-mini-3.8b --smoke \
       --policy proportional --requests 6
 
-Smoke mode runs real JAX inference per worker group on CPU with reduced
-variant configs; production mode targets the pod mesh with analytic
-profiling (SimBackend) for dispatch decisions and pjit'd engines per group.
+The gateway plans every request with the analytic profiling table
+(SimBackend). With ``--smoke`` each node's share is then served by a real
+engine through :class:`ShareRunner`: at the smoke config on the CPU (kernels
+interpreted), at the full published config on a TPU. Node j's engine sits
+on device j mod the device count.
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
-from typing import Dict, List
+from typing import Dict, List, Sequence, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import ARCH_NAMES, get_config, get_smoke_config
+from repro.configs import ARCH_NAMES, ModelConfig, get_config, get_smoke_config
 from repro.core.cluster import DEFAULT_NODES, SimBackend
 from repro.sched import registered_policies
 from repro.core.profiling import NodeProfile, ProfilingTable
-from repro.core.requests import InferenceRequest
+from repro.core.requests import Dispatch, InferenceRequest
 from repro.core.resource_manager import Event, GatewayNode
 from repro.core.variants import VariantPool
-from repro.models import model as model_lib
-from repro.serving.engine import Engine, EngineConfig
+from repro.launch.compile_cache import enable_compile_cache
+from repro.serving.engine import Engine, EngineConfig, init_params_on
+
+# Each share runs one engine batch of at most SHARE_ITEMS prompts of
+# PROMPT_LEN tokens (the profiling table's seq_len), then DECODE_STEPS
+# greedy decode steps.
+SHARE_ITEMS = 8
+PROMPT_LEN = 512
+DECODE_STEPS = 4
+# decode cache slots: the decode kernel tiles the cache in multiples of 128
+CACHE_LEN = -(-(PROMPT_LEN + DECODE_STEPS) // 128) * 128
 
 
 def build_gateway(cfg, *, policy: str = "proportional",
-                  nodes=DEFAULT_NODES, seq_len: int = 512,
+                  nodes=DEFAULT_NODES, seq_len: int = PROMPT_LEN,
                   noise_std: float = 0.0, seed: int = 0) -> GatewayNode:
     pool = VariantPool(cfg)
     node_profiles = [NodeProfile(n.name, n.chips, n.capability) for n in nodes]
@@ -56,26 +69,127 @@ def demo_requests(gn: GatewayNode, n: int, seed: int = 0) -> List[InferenceReque
     return out
 
 
-def smoke_inference(cfg_smoke, gn: GatewayNode, request: InferenceRequest,
-                    seed: int = 0) -> Dict[str, float]:
-    """Actually run the dispatched shares through JAX engines on CPU, one
-    engine per (node, variant) — the LN Inference state with real compute."""
-    d = gn.dispatches[-1]
-    pool = VariantPool(cfg_smoke)
-    rng = jax.random.PRNGKey(seed)
-    timings = {}
-    for a in d.assignments:
-        if a.items == 0:
-            continue
-        vcfg = pool[a.apx_level].config
-        params = model_lib.init_params(vcfg, rng)
-        eng = Engine(vcfg, params, EngineConfig(max_len=64))
-        toks = jax.random.randint(rng, (min(a.items, 4), 16), 0,
-                                  vcfg.vocab_size)
-        t0 = time.time()
-        eng.generate(toks, num_steps=4)
-        timings[a.node] = time.time() - t0
-    return timings
+@dataclasses.dataclass
+class ShareResult:
+    """One node's share of one request, as its engine served it."""
+    node: str
+    level: int
+    device: str
+    items: int                 # items the plan gave the node
+    served: int                # items the engine ran: min(items, SHARE_ITEMS)
+    logits: np.ndarray         # (served, vocab) prefill logits, float32
+    tokens: np.ndarray         # (served, decode_steps) greedy tokens
+    build_s: float             # weight init on the device; 0 when resident
+    compile_s: Dict[str, float]   # per program; near 0 when already compiled
+    prefill_s: float
+    decode_step_s: float
+
+
+class ShareRunner:
+    """Serves each share of a dispatch on a real engine.
+
+    ``placement`` maps each node to the device its engine sits on (see
+    :func:`place_nodes`); several nodes may share a device. A device holds
+    one level at a time (one full-width level fills most of a 16 GB chip):
+    the engine for the level the plan assigns is built there, after the
+    previous level's weights are freed. Shares that share a device run in
+    level order, so that a request swaps each level in at most once.
+
+    Each share runs one engine batch of ``min(items, SHARE_ITEMS)`` prompts
+    of ``PROMPT_LEN`` tokens, then ``DECODE_STEPS`` greedy decode steps.
+    Prompts are drawn from ``(rid, node)`` and weights from the level, so the
+    same dispatch gives the same inputs under any placement.
+    """
+
+    def __init__(self, cfg: ModelConfig, placement: Dict[str, jax.Device]):
+        self.pool = VariantPool(cfg)
+        self.placement = dict(placement)
+        self._node_idx = {name: j for j, name in enumerate(self.placement)}
+        self.ecfg = EngineConfig(max_len=CACHE_LEN)
+        self.resident: Dict[jax.Device, Tuple[int, Engine]] = {}
+
+    def _engine(self, device: jax.Device, level: int) -> Tuple[Engine, float]:
+        """The engine for ``level`` on ``device``, and the seconds spent
+        building its weights (0 when it was already resident)."""
+        cur = self.resident.get(device)
+        if cur is not None and cur[0] == level:
+            return cur[1], 0.0
+        if cur is not None:
+            cur[1].release()
+        vcfg = self.pool[level].config
+        t0 = time.perf_counter()
+        rng = jax.random.fold_in(jax.random.PRNGKey(0), level)
+        params = jax.block_until_ready(init_params_on(vcfg, rng, device))
+        build_s = time.perf_counter() - t0
+        eng = Engine(vcfg, params, self.ecfg, device=device)
+        self.resident[device] = (level, eng)
+        return eng, build_s
+
+    def _prompts(self, rid: int, node: str, n: int, vocab: int) -> np.ndarray:
+        rng = np.random.default_rng([rid, self._node_idx[node]])
+        return rng.integers(0, vocab, (n, PROMPT_LEN), dtype=np.int32)
+
+    def run(self, d: Dispatch) -> List[ShareResult]:
+        shares = [a for a in d.assignments if a.items > 0]
+        shares.sort(key=lambda a: (self.placement[a.node].id, a.apx_level))
+        return [self._serve(d.request.rid, a) for a in shares]
+
+    def _serve(self, rid: int, a) -> ShareResult:
+        device = self.placement[a.node]
+        eng, build_s = self._engine(device, a.apx_level)
+        n = min(a.items, SHARE_ITEMS)
+        tokens = jax.device_put(
+            self._prompts(rid, a.node, n, eng.cfg.vocab_size), device)
+        compile_s = eng.compile(tokens)
+
+        t0 = time.perf_counter()
+        logits, caches, lengths = eng.prefill(tokens)
+        jax.block_until_ready((logits, caches))
+        prefill_s = time.perf_counter() - t0
+
+        first = logits
+        out = []
+        t0 = time.perf_counter()
+        for _ in range(DECODE_STEPS):
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            out.append(tok)
+            logits, caches, lengths = eng.decode(caches, lengths, tok)
+        jax.block_until_ready((out, logits))
+        decode_step_s = (time.perf_counter() - t0) / DECODE_STEPS
+        return ShareResult(
+            node=a.node, level=a.apx_level, device=str(device), items=a.items,
+            served=n, logits=np.asarray(first, np.float32),
+            tokens=np.stack([np.asarray(t) for t in out], axis=1),
+            build_s=build_s, compile_s=compile_s, prefill_s=prefill_s,
+            decode_step_s=decode_step_s)
+
+    def close(self):
+        """Free every resident engine's weights."""
+        for _, eng in self.resident.values():
+            eng.release()
+        self.resident.clear()
+
+
+def place_nodes(nodes: Sequence[str],
+                devices: Sequence[jax.Device]) -> Dict[str, jax.Device]:
+    """Node j on device j mod the device count."""
+    return {name: devices[j % len(devices)] for j, name in enumerate(nodes)}
+
+
+def serving_config(arch: str) -> ModelConfig:
+    """The config engines serve: full width on a TPU, the smoke config on
+    the CPU."""
+    if jax.devices()[0].platform == "tpu":
+        return get_config(arch)
+    return get_smoke_config(arch)
+
+
+def format_share(r: ShareResult) -> str:
+    comp = " ".join(f"{k}={v:.3f}s" for k, v in r.compile_s.items())
+    return (f"     {r.node} level={r.level} on {r.device}: "
+            f"served {r.served}/{r.items} items, build={r.build_s:.3f}s "
+            f"compile[{comp}] prefill={r.prefill_s:.4f}s "
+            f"decode_step={r.decode_step_s:.4f}s")
 
 
 def main(argv=None):
@@ -85,7 +199,8 @@ def main(argv=None):
                     default="proportional")
     ap.add_argument("--requests", type=int, default=6)
     ap.add_argument("--smoke", action="store_true",
-                    help="run real reduced-config inference per share on CPU")
+                    help="serve each share on a real engine: the smoke "
+                         "config on the CPU, the full config on a TPU")
     ap.add_argument("--disconnect", action="store_true",
                     help="disconnect a node mid-trace (paper Fig. 9)")
     args = ap.parse_args(argv)
@@ -93,6 +208,14 @@ def main(argv=None):
     cfg = get_config(args.arch)
     gn = build_gateway(cfg, policy=args.policy)
     reqs = demo_requests(gn, args.requests)
+    runner = None
+    if args.smoke:
+        enable_compile_cache()
+        devices = jax.devices()
+        runner = ShareRunner(serving_config(args.arch), place_nodes(
+            [n.name for n in gn.table.nodes], devices))
+        print(f"serving shares on {len(devices)} {devices[0].device_kind} "
+              f"device(s), at most {SHARE_ITEMS} items per share")
 
     print(f"policy={args.policy} arch={args.arch}")
     print(f"{'rid':>3} {'items':>6} {'perf_req':>10} {'acc_req':>7} "
@@ -107,10 +230,11 @@ def main(argv=None):
               f"{r.acc_req:7.2f} {res.achieved_perf:10.1f} "
               f"{res.achieved_acc:6.2f} "
               f"{'y' if res.meets_perf and res.meets_acc else 'N':>5}")
-        if args.smoke:
-            t = smoke_inference(get_smoke_config(args.arch), gn, r)
-            print(f"     smoke per-node wall: "
-                  f"{ {k: round(v, 3) for k, v in t.items()} }")
+        if runner is not None:
+            for share in runner.run(gn.dispatches[-1]):
+                print(format_share(share))
+    if runner is not None:
+        runner.close()
     print("summary:", {k: round(v, 4) for k, v in gn.summary().items()})
 
 

@@ -43,7 +43,8 @@ def init_from_specs(rng: jax.Array, specs: Dict[str, Any], dtype=jnp.float32) ->
         else:
             fan_in = spec.shape[0] if len(spec.shape) > 1 else max(spec.shape[-1], 1)
             std = spec.scale / math.sqrt(fan_in)
-            leaves.append((jax.random.normal(r, spec.shape) * std).astype(dtype))
+            # drawn in the target dtype: no float32 copy of a bf16 weight
+            leaves.append(jax.random.normal(r, spec.shape, dtype) * std)
     return jax.tree_util.tree_unflatten(treedef, leaves)
 
 

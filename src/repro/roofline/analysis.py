@@ -10,8 +10,9 @@ verify this convention in tests/test_roofline.py. Collective bytes are not
 in cost_analysis — we parse the post-optimization HLO text and sum wire
 traffic per collective with ring-algorithm factors.
 
-TPU v5e hardware constants (per chip): 197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s/link ICI (and ~4x lower for the cross-pod DCN "pod" axis).
+Hardware peaks come from one table keyed by the ``device_kind`` JAX
+reports; a kind that is not in it is an error, never a default. The
+dry-run models v5e pods, so it reads the v5e entry by name.
 """
 from __future__ import annotations
 
@@ -19,9 +20,35 @@ import dataclasses
 import re
 from typing import Dict
 
-PEAK_FLOPS = 197e12          # bf16 / chip
-HBM_BW = 819e9               # bytes/s / chip
-ICI_BW = 50e9                # bytes/s / link
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published peaks of one chip."""
+    flops: float             # bf16 FLOP/s
+    hbm_bw: float            # HBM bytes/s
+    ici_bw: float            # chip-to-chip bytes/s per link
+    hbm_bytes: float         # HBM capacity
+
+
+V5E = "TPU v5 lite"
+
+# Source: Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB of
+# HBM at 819 GB/s, 1,600 Gbit/s of interconnect over four links.
+PEAKS: Dict[str, ChipPeaks] = {
+    V5E: ChipPeaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9, hbm_bytes=16e9),
+}
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """Peaks of the chip JAX reports as ``device_kind``."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device_kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}") from None
+
+
+_POD = chip_peaks(V5E)     # the chip the dry-run's pods are built from
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -141,7 +168,7 @@ class Roofline:
         """useful-compute time / bound time — the score we hillclimb."""
         if self.bound_s <= 0:
             return 0.0
-        return (self.model_flops / PEAK_FLOPS) / self.bound_s
+        return (self.model_flops / _POD.flops) / self.bound_s
 
 
 def analyze(compiled, hlo_text: str, *, model_flops_per_device: float = 0.0,
@@ -167,9 +194,9 @@ def analyze(compiled, hlo_text: str, *, model_flops_per_device: float = 0.0,
         flops=flops,
         hbm_bytes=hbm,
         collective_bytes=wire,
-        compute_s=flops / PEAK_FLOPS,
-        memory_s=hbm / HBM_BW,
-        collective_s=wire / (ICI_BW * links_per_chip),
+        compute_s=flops / _POD.flops,
+        memory_s=hbm / _POD.hbm_bw,
+        collective_s=wire / (_POD.ici_bw * links_per_chip),
         collectives=coll,
         model_flops=model_flops_per_device,
         peak_mem_bytes=peak,

@@ -1,8 +1,8 @@
 """Serving engine: prefill -> padded decode caches -> batched decode loop.
 
 The engine owns the jit'd prefill/decode executables for one model variant
-on one worker group (mesh). The paper's Local Node "Inference" state calls
-into this; the Gateway's dispatcher decides which variant each group loads.
+on one device. The paper's Local Node "Inference" state calls into this; the
+Gateway's dispatcher decides which variant each node loads.
 
 Cache layout notes:
   * prefill returns raw seq-length caches; ``pad_caches`` places them into
@@ -15,7 +15,8 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import List, Optional
+import time
+from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -76,6 +77,19 @@ def pad_caches(cfg: ModelConfig, raw_caches, seq_len: int, max_len: int):
     return out
 
 
+@functools.lru_cache(maxsize=32)
+def _init_program(cfg: ModelConfig):
+    return jax.jit(functools.partial(tfm.init_params, cfg,
+                                     dtype=jnp.dtype(cfg.dtype)))
+
+
+def init_params_on(cfg: ModelConfig, rng: jax.Array, device: jax.Device):
+    """Random serving weights in the config's dtype, drawn by one jitted
+    program on ``device`` (it runs where its key is committed): no float32
+    copy of the model is ever materialised, on the host or on the device."""
+    return _init_program(cfg)(jax.device_put(rng, device))
+
+
 @dataclasses.dataclass
 class EngineConfig:
     max_len: int = 512
@@ -83,29 +97,71 @@ class EngineConfig:
     donate_cache: bool = True
 
 
-class Engine:
-    """One model variant, jit'd, on the current default mesh/devices."""
+@functools.lru_cache(maxsize=32)
+def _programs(cfg: ModelConfig, use_kernels: bool, donate_cache: bool):
+    """Jitted prefill/decode, shared by every engine of one variant so that
+    an engine rebuilt on a device reuses the programs compiled there."""
+    prefill = jax.jit(functools.partial(
+        model_lib.prefill, cfg, use_kernels=use_kernels))
+    decode = jax.jit(
+        functools.partial(model_lib.decode_step, cfg,
+                          use_kernels=use_kernels),
+        donate_argnums=(1,) if donate_cache else ())
+    return prefill, decode
 
-    def __init__(self, cfg: ModelConfig, params, ecfg: EngineConfig = EngineConfig()):
+
+class Engine:
+    """One model variant, jit'd, on one device (default: the first)."""
+
+    def __init__(self, cfg: ModelConfig, params,
+                 ecfg: EngineConfig = EngineConfig(),
+                 device: Optional[jax.Device] = None):
         self.cfg = cfg
-        self.params = params
         self.ecfg = ecfg
-        self._prefill = jax.jit(functools.partial(
-            model_lib.prefill, cfg, use_kernels=ecfg.use_kernels))
-        self._decode = jax.jit(
-            functools.partial(model_lib.decode_step, cfg,
-                              use_kernels=ecfg.use_kernels),
-            donate_argnums=(1,) if ecfg.donate_cache else ())
+        self.device = device or jax.devices()[0]
+        self.params = jax.device_put(params, self.device)
+        self._prefill, self._decode = _programs(cfg, ecfg.use_kernels,
+                                                ecfg.donate_cache)
 
     def prefill(self, tokens: jax.Array, embeds: Optional[jax.Array] = None):
+        tokens = jax.device_put(tokens, self.device)
+        if embeds is not None:
+            embeds = jax.device_put(embeds, self.device)
         logits, raw = self._prefill(self.params, tokens, embeds)
         seq_len = tokens.shape[1] + (embeds.shape[1] if embeds is not None else 0)
         caches = pad_caches(self.cfg, raw, seq_len, self.ecfg.max_len)
-        lengths = jnp.full((tokens.shape[0],), seq_len, jnp.int32)
+        lengths = jnp.full((tokens.shape[0],), seq_len, jnp.int32,
+                           device=self.device)
         return logits, caches, lengths
 
     def decode(self, caches, lengths, tokens):
-        return self._decode(self.params, caches, lengths, tokens)
+        return self._decode(self.params, caches, lengths,
+                            jax.device_put(tokens, self.device))
+
+    def compile(self, tokens: jax.Array) -> Dict[str, float]:
+        """Compile prefill and decode for this batch shape ahead of serving
+        (a later call with the same shapes reuses the executables). Returns
+        the compile seconds of each program; runs one prefill to get the
+        decode cache it compiles against."""
+        tokens = jax.device_put(tokens, self.device)
+        t0 = time.perf_counter()
+        self._prefill.lower(self.params, tokens, None).compile()
+        out = {"prefill": time.perf_counter() - t0}
+        logits, caches, lengths = self.prefill(tokens)
+        # finish it here, or it runs into the caller's next timed call
+        jax.block_until_ready((logits, caches))
+        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        t0 = time.perf_counter()
+        self._decode.lower(self.params, caches, lengths, tok).compile()
+        out["decode"] = time.perf_counter() - t0
+        return out
+
+    def release(self):
+        """Free this engine's weights on its device now, so that the next
+        level's weights fit (a chip holds one full-width level at a time)."""
+        for leaf in jax.tree_util.tree_leaves(self.params):
+            leaf.delete()
+        self.params = None
 
     def generate(self, tokens: jax.Array, num_steps: int,
                  embeds: Optional[jax.Array] = None,

@@ -1,5 +1,7 @@
 """Per-kernel shape/dtype sweeps: Pallas (interpret=True on CPU) vs the
 pure-jnp oracles in kernels/ref.py."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -41,7 +43,8 @@ def test_flash_attention(b, h, kv, s, d, window, softcap, dtype, rng):
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("b,kv,g,s,d", [(2, 4, 2, 256, 64), (1, 8, 1, 128, 128),
-                                        (2, 2, 8, 192, 64)])
+                                        (2, 2, 8, 192, 64),
+                                        (2, 8, 3, 160, 128)])  # s % block_k
 def test_decode_attention(b, kv, g, s, d, dtype, rng):
     ks = jax.random.split(rng, 4)
     q = jax.random.normal(ks[0], (b, kv, g, d), dtype)
@@ -113,3 +116,42 @@ def test_model_kernel_integration(rng):
         l1, _ = forward(cfg, params, toks, use_kernels=True)
         np.testing.assert_allclose(np.asarray(l0), np.asarray(l1),
                                    atol=5e-4, rtol=5e-4)
+
+
+def test_interpret_only_on_cpu(monkeypatch):
+    """Kernels compile on TPU and interpret on the CPU; any other backend
+    is an error, never a silent interpreter."""
+    from repro.kernels import ops
+    for backend, interpret in (("cpu", True), ("tpu", False)):
+        monkeypatch.setattr(ops.jax, "default_backend", lambda b=backend: b)
+        assert ops._interpret() is interpret
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        ops._interpret()
+
+
+@pytest.mark.parametrize("window,softcap", [(None, 0.0), (32, 20.0)])
+def test_flash_attention_grad(window, softcap, rng):
+    """The custom_vjp backward kernels (lse/delta as (B, H, Sq, 1)) match
+    autodiff through the oracle."""
+    from repro.kernels import ops
+    ks = jax.random.split(rng, 4)
+    b, s, h, kv, d = 1, 128, 4, 2, 64
+    q = jax.random.normal(ks[0], (b, s, h, d), jnp.float32)
+    k = jax.random.normal(ks[1], (b, s, kv, d), jnp.float32)
+    v = jax.random.normal(ks[2], (b, s, kv, d), jnp.float32)
+    ct = jax.random.normal(ks[3], (b, s, h, d), jnp.float32)
+
+    def loss(fn, q, k, v):
+        return jnp.sum(fn(q, k, v) * ct)
+
+    kern = lambda q, k, v: ops.flash_attention(
+        q, k, v, window=window, attn_softcap=softcap)
+    oracle = lambda q, k, v: jnp.swapaxes(ref.flash_attention_ref(
+        *(jnp.swapaxes(x, 1, 2) for x in (q, k, v)), causal=True,
+        window=window, softcap=softcap), 1, 2)
+    got = jax.grad(functools.partial(loss, kern), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(functools.partial(loss, oracle), argnums=(0, 1, 2))(q, k, v)
+    for g_, w_ in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g_), np.asarray(w_),
+                                   atol=2e-4, rtol=2e-4)

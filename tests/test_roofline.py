@@ -81,11 +81,21 @@ def test_model_flops_conventions():
 
 
 def test_roofline_dominant_term():
+    v5e = ra.chip_peaks(ra.V5E)
     r = ra.Roofline(flops=1e12, hbm_bytes=1e9, collective_bytes=1e6,
-                    compute_s=1e12 / ra.PEAK_FLOPS,
-                    memory_s=1e9 / ra.HBM_BW,
-                    collective_s=1e6 / ra.ICI_BW,
+                    compute_s=1e12 / v5e.flops,
+                    memory_s=1e9 / v5e.hbm_bw,
+                    collective_s=1e6 / v5e.ici_bw,
                     collectives=ra.CollectiveStats({}, {}),
                     model_flops=5e11)
     assert r.dominant == "compute"
     assert 0 < r.roofline_fraction <= 1
+
+
+def test_chip_peaks_unknown_kind_raises():
+    """Peaks come from the table keyed by device_kind; an unknown kind is an
+    error, never the v5e default."""
+    v5e = ra.chip_peaks("TPU v5 lite")
+    assert v5e.flops == 197e12 and v5e.hbm_bw == 819e9
+    with pytest.raises(KeyError, match="TPU v99"):
+        ra.chip_peaks("TPU v99")

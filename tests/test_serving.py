@@ -137,3 +137,36 @@ def test_batch_scheduler_fifo_order_across_batches():
             seen.append(row[row != 0].tolist())
     assert seen == [p.tolist() for p in prompts]
     assert sched.next_batch() is None
+
+
+def test_share_runner_builds_bf16_params_on_its_device():
+    """The share runner serves each share of a real plan on the device its
+    node is placed on, with weights in the config's dtype (bf16)."""
+    from repro.configs import get_config
+    from repro.core.resource_manager import Event
+    from repro.launch.serve import (DECODE_STEPS, SHARE_ITEMS, ShareRunner,
+                                    build_gateway, demo_requests,
+                                    place_nodes)
+    gn = build_gateway(get_config("phi4-mini-3.8b"))
+    device = jax.devices()[-1]
+    runner = ShareRunner(get_smoke_config("phi4-mini-3.8b"),
+                         place_nodes([n.name for n in gn.table.nodes],
+                                     [device]))
+    req = demo_requests(gn, 1)[0]
+    gn.handle(Event(kind="workload", request=req))
+    shares = runner.run(gn.dispatches[-1])
+    assert {s.node for s in shares} == {
+        a.node for a in gn.dispatches[-1].assignments if a.items}
+    for s in shares:
+        assert s.served == min(s.items, SHARE_ITEMS)
+        assert s.tokens.shape == (s.served, DECODE_STEPS)
+        assert 0 <= s.tokens.min() and s.tokens.max() < 256
+        assert s.logits.shape == (s.served, 256)
+        assert np.isfinite(s.logits).all()
+    (level, eng), = runner.resident.values()
+    assert level == shares[-1].level
+    leaves = jax.tree_util.tree_leaves(eng.params)
+    assert {leaf.dtype for leaf in leaves} == {jnp.dtype(jnp.bfloat16)}
+    assert all(leaf.devices() == {device} for leaf in leaves)
+    runner.close()
+    assert eng.params is None and not runner.resident

@@ -105,3 +105,12 @@ def test_cache_shardings_cover_arch(arch, mesh):
     shardings = shd.cache_shardings(rules, cfg, batch=128, max_len=32768)
     for leaf in jax.tree_util.tree_leaves(shardings):
         assert leaf.spec is not None
+
+
+def test_make_local_mesh_refuses_more_devices_than_exist():
+    """Asking for more devices than exist is an error, not a smaller mesh."""
+    from repro.launch.mesh import make_local_mesh
+    n = jax.device_count()
+    assert make_local_mesh(data=n).devices.size == n
+    with pytest.raises(ValueError, match="devices"):
+        make_local_mesh(data=n + 1)
