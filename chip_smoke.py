@@ -140,6 +140,7 @@ def phase_check(cfg, device) -> None:
     import jax.numpy as jnp
     import numpy as np
     from repro.launch.serve import CACHE_LEN, PROMPT_LEN
+    from repro.serving import spans
     from repro.serving.engine import Engine, EngineConfig, init_params_on
 
     params, build_s = _timed(
@@ -154,7 +155,10 @@ def phase_check(cfg, device) -> None:
         0, cfg.vocab_size, (CHECK_BATCH, PROMPT_LEN), dtype=np.int32), device)
     out = {}
     for name, eng in engines.items():
-        for prog, s in eng.compile(tokens).items():
+        with spans.collect("check.compile") as got:
+            eng.compile(tokens)
+        for prog in ("prefill", "decode"):
+            s = spans.seconds(got, f"engine.aot_{prog}")
             print(f"compile {name} {prog}: {s:.3f} s", flush=True)
         (logits, caches, lengths), s = _timed(lambda: eng.prefill(tokens))
         print(f"{name} prefill B={CHECK_BATCH} S={PROMPT_LEN}: {s:.4f} s",

@@ -14,8 +14,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
-import time
-from typing import Dict, List, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -29,6 +28,7 @@ from repro.core.requests import Dispatch, InferenceRequest
 from repro.core.resource_manager import Event, GatewayNode
 from repro.core.variants import VariantPool
 from repro.launch.compile_cache import enable_compile_cache
+from repro.serving import spans
 from repro.serving.engine import Engine, EngineConfig, init_params_on
 
 # Each share runs one engine batch of at most SHARE_ITEMS prompts of
@@ -71,7 +71,8 @@ def demo_requests(gn: GatewayNode, n: int, seed: int = 0) -> List[InferenceReque
 
 @dataclasses.dataclass
 class ShareResult:
-    """One node's share of one request, as its engine served it."""
+    """One node's share of one request, as its engine served it. The
+    seconds are the durations of the share's spans (``ShareRunner``)."""
     node: str
     level: int
     device: str
@@ -79,10 +80,13 @@ class ShareResult:
     served: int                # items the engine ran: min(items, SHARE_ITEMS)
     logits: np.ndarray         # (served, vocab) prefill logits, float32
     tokens: np.ndarray         # (served, decode_steps) greedy tokens
-    build_s: float             # weight init on the device; 0 when resident
-    compile_s: Dict[str, float]   # per program; near 0 when already compiled
-    prefill_s: float
-    decode_step_s: float
+    build_s: float             # ``runner.build``: drawing the level's weights; 0 when resident
+    compile_s: Dict[str, float]   # per program (``engine.aot_*``); near 0 when compiled
+    prefill_s: float           # ``engine.prefill``
+    decode_step_s: float       # ``engine.decode`` / DECODE_STEPS
+    rid: Optional[int] = None  # the request's id
+    spans: Tuple[spans.Span, ...] = ()   # ``runner.share`` first
+    compiles: int = 0          # programs compiled or loaded during the share
 
 
 class ShareRunner:
@@ -108,22 +112,23 @@ class ShareRunner:
         self.ecfg = EngineConfig(max_len=CACHE_LEN)
         self.resident: Dict[jax.Device, Tuple[int, Engine]] = {}
 
-    def _engine(self, device: jax.Device, level: int) -> Tuple[Engine, float]:
-        """The engine for ``level`` on ``device``, and the seconds spent
-        building its weights (0 when it was already resident)."""
+    def _engine(self, device: jax.Device, level: int) -> Engine:
+        """The engine for ``level`` on ``device``. Swapping it in is
+        ``runner.release`` (freeing the resident level), then
+        ``runner.build`` (drawing the new weights: ``build_s``)."""
         cur = self.resident.get(device)
         if cur is not None and cur[0] == level:
-            return cur[1], 0.0
+            return cur[1]
         if cur is not None:
-            cur[1].release()
+            with spans.record("runner.release"):
+                cur[1].release()
         vcfg = self.pool[level].config
-        t0 = time.perf_counter()
-        rng = jax.random.fold_in(jax.random.PRNGKey(0), level)
-        params = jax.block_until_ready(init_params_on(vcfg, rng, device))
-        build_s = time.perf_counter() - t0
+        with spans.record("runner.build"):
+            rng = jax.random.fold_in(jax.random.PRNGKey(0), level)
+            params = jax.block_until_ready(init_params_on(vcfg, rng, device))
         eng = Engine(vcfg, params, self.ecfg, device=device)
         self.resident[device] = (level, eng)
-        return eng, build_s
+        return eng
 
     def _prompts(self, rid: int, node: str, n: int, vocab: int) -> np.ndarray:
         rng = np.random.default_rng([rid, self._node_idx[node]])
@@ -132,36 +137,46 @@ class ShareRunner:
     def run(self, d: Dispatch) -> List[ShareResult]:
         shares = [a for a in d.assignments if a.items > 0]
         shares.sort(key=lambda a: (self.placement[a.node].id, a.apx_level))
-        return [self._serve(d.request.rid, a) for a in shares]
+        with spans.record("runner.run"):
+            return [self._serve(d.request.rid, a) for a in shares]
 
     def _serve(self, rid: int, a) -> ShareResult:
         device = self.placement[a.node]
-        eng, build_s = self._engine(device, a.apx_level)
-        n = min(a.items, SHARE_ITEMS)
-        tokens = jax.device_put(
-            self._prompts(rid, a.node, n, eng.cfg.vocab_size), device)
-        compile_s = eng.compile(tokens)
+        compiled = spans.compiles()
+        with spans.collect("runner.share") as got:
+            eng = self._engine(device, a.apx_level)
+            n = min(a.items, SHARE_ITEMS)
+            with spans.record("runner.prompts"):
+                tokens = jax.device_put(
+                    self._prompts(rid, a.node, n, eng.cfg.vocab_size), device)
+            eng.compile(tokens)
 
-        t0 = time.perf_counter()
-        logits, caches, lengths = eng.prefill(tokens)
-        jax.block_until_ready((logits, caches))
-        prefill_s = time.perf_counter() - t0
+            with spans.record("engine.prefill"):
+                logits, caches, lengths = eng.prefill(tokens)
+                jax.block_until_ready((logits, caches))
 
-        first = logits
-        out = []
-        t0 = time.perf_counter()
-        for _ in range(DECODE_STEPS):
-            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            out.append(tok)
-            logits, caches, lengths = eng.decode(caches, lengths, tok)
-        jax.block_until_ready((out, logits))
-        decode_step_s = (time.perf_counter() - t0) / DECODE_STEPS
+            first = logits
+            out = []
+            with spans.record("engine.decode"):
+                for _ in range(DECODE_STEPS):
+                    tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                    out.append(tok)
+                    logits, caches, lengths = eng.decode(caches, lengths, tok)
+                jax.block_until_ready((out, logits))
+
+            with spans.record("runner.fetch"):
+                first = np.asarray(first, np.float32)
+                out = np.stack([np.asarray(t) for t in out], axis=1)
+        got = tuple(got)
         return ShareResult(
             node=a.node, level=a.apx_level, device=str(device), items=a.items,
-            served=n, logits=np.asarray(first, np.float32),
-            tokens=np.stack([np.asarray(t) for t in out], axis=1),
-            build_s=build_s, compile_s=compile_s, prefill_s=prefill_s,
-            decode_step_s=decode_step_s)
+            served=n, logits=first, tokens=out,
+            build_s=spans.seconds(got, "runner.build"),
+            compile_s={"prefill": spans.seconds(got, "engine.aot_prefill"),
+                       "decode": spans.seconds(got, "engine.aot_decode")},
+            prefill_s=spans.seconds(got, "engine.prefill"),
+            decode_step_s=spans.seconds(got, "engine.decode") / DECODE_STEPS,
+            rid=rid, spans=got, compiles=spans.compiles() - compiled)
 
     def close(self):
         """Free every resident engine's weights."""
@@ -189,7 +204,7 @@ def format_share(r: ShareResult) -> str:
     return (f"     {r.node} level={r.level} on {r.device}: "
             f"served {r.served}/{r.items} items, build={r.build_s:.3f}s "
             f"compile[{comp}] prefill={r.prefill_s:.4f}s "
-            f"decode_step={r.decode_step_s:.4f}s")
+            f"decode_step={r.decode_step_s:.4f}s compiles={r.compiles}")
 
 
 def main(argv=None):

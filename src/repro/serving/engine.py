@@ -15,8 +15,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import time
-from typing import Dict, List, Optional
+from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -27,6 +26,7 @@ from repro.core.batching import BatchFormation
 from repro.models import attention as attn_lib
 from repro.models import model as model_lib
 from repro.models import transformer as tfm
+from repro.serving.spans import record
 
 
 def _pad_kv(raw: attn_lib.KVCache, max_len: int, seq_len: int,
@@ -77,10 +77,19 @@ def pad_caches(cfg: ModelConfig, raw_caches, seq_len: int, max_len: int):
     return out
 
 
+def _named(f, *args, **kwargs):
+    """``functools.partial(f, ...)`` under ``f``'s name, so that its jitted
+    program is ``jit_<name>`` in traces and compile logs (a bare partial is
+    ``jit__unknown``). Only the name is copied: ``functools.wraps`` would
+    also point ``inspect.signature`` at ``f``'s unbound arguments."""
+    p = functools.partial(f, *args, **kwargs)
+    p.__name__ = f.__name__
+    return p
+
+
 @functools.lru_cache(maxsize=32)
 def _init_program(cfg: ModelConfig):
-    return jax.jit(functools.partial(tfm.init_params, cfg,
-                                     dtype=jnp.dtype(cfg.dtype)))
+    return jax.jit(_named(tfm.init_params, cfg, dtype=jnp.dtype(cfg.dtype)))
 
 
 def init_params_on(cfg: ModelConfig, rng: jax.Array, device: jax.Device):
@@ -101,11 +110,9 @@ class EngineConfig:
 def _programs(cfg: ModelConfig, use_kernels: bool, donate_cache: bool):
     """Jitted prefill/decode, shared by every engine of one variant so that
     an engine rebuilt on a device reuses the programs compiled there."""
-    prefill = jax.jit(functools.partial(
-        model_lib.prefill, cfg, use_kernels=use_kernels))
+    prefill = jax.jit(_named(model_lib.prefill, cfg, use_kernels=use_kernels))
     decode = jax.jit(
-        functools.partial(model_lib.decode_step, cfg,
-                          use_kernels=use_kernels),
+        _named(model_lib.decode_step, cfg, use_kernels=use_kernels),
         donate_argnums=(1,) if donate_cache else ())
     return prefill, decode
 
@@ -138,23 +145,25 @@ class Engine:
         return self._decode(self.params, caches, lengths,
                             jax.device_put(tokens, self.device))
 
-    def compile(self, tokens: jax.Array) -> Dict[str, float]:
+    def compile(self, tokens: jax.Array) -> None:
         """Compile prefill and decode for this batch shape ahead of serving
-        (a later call with the same shapes reuses the executables). Returns
-        the compile seconds of each program; runs one prefill to get the
-        decode cache it compiles against."""
+        (a later call with the same shapes reuses the executables). Runs one
+        prefill to get the decode cache it compiles against. Spans:
+        ``engine.compile`` around it all; inside, ``engine.aot_prefill`` and
+        ``engine.aot_decode`` around each program's compile (near 0 when it
+        is already compiled) and ``engine.compile_prefill`` around the
+        extra prefill."""
         tokens = jax.device_put(tokens, self.device)
-        t0 = time.perf_counter()
-        self._prefill.lower(self.params, tokens, None).compile()
-        out = {"prefill": time.perf_counter() - t0}
-        logits, caches, lengths = self.prefill(tokens)
-        # finish it here, or it runs into the caller's next timed call
-        jax.block_until_ready((logits, caches))
-        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        t0 = time.perf_counter()
-        self._decode.lower(self.params, caches, lengths, tok).compile()
-        out["decode"] = time.perf_counter() - t0
-        return out
+        with record("engine.compile"):
+            with record("engine.aot_prefill"):
+                self._prefill.lower(self.params, tokens, None).compile()
+            with record("engine.compile_prefill"):
+                logits, caches, lengths = self.prefill(tokens)
+                # finish it here, or it runs into the caller's next timed call
+                jax.block_until_ready((logits, caches))
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            with record("engine.aot_decode"):
+                self._decode.lower(self.params, caches, lengths, tok).compile()
 
     def release(self):
         """Free this engine's weights on its device now, so that the next

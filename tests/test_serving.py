@@ -170,3 +170,174 @@ def test_share_runner_builds_bf16_params_on_its_device():
     assert all(leaf.devices() == {device} for leaf in leaves)
     runner.close()
     assert eng.params is None and not runner.resident
+
+
+# --- spans and the compile counter (repro.serving.spans) ---
+
+def _one_share_dispatch(node, items, level=0, rid=7):
+    from repro.core.requests import Assignment, Dispatch, InferenceRequest
+    req = InferenceRequest(rid=rid, num_items=items, perf_req=1.0,
+                           acc_req=0.0)
+    return Dispatch(request=req, policy="test", assignments=(Assignment(
+        node=node, items=items, apx_level=level, perf_alloc=0.0),))
+
+
+@pytest.fixture(scope="module")
+def served_twice():
+    """A smoke-config runner on one device serving a share of a batch shape
+    no other test serves, then the same share again."""
+    from repro.launch.serve import ShareRunner, place_nodes
+    runner = ShareRunner(get_smoke_config("phi4-mini-3.8b"),
+                         place_nodes(["n0", "n1"], jax.devices()[:1]))
+    d = _one_share_dispatch("n0", items=3)
+    first, = runner.run(d)
+    again, = runner.run(d)
+    other, = runner.run(_one_share_dispatch("n1", items=3, level=1))
+    runner.close()
+    return first, again, other
+
+
+def test_share_spans_nest_on_one_thread(served_twice):
+    for r in served_twice:
+        assert r.spans[0].name == "runner.share" and r.spans[0].parent is None
+        for i, s in enumerate(r.spans[1:], 1):
+            p = r.spans[s.parent]
+            assert s.parent < i
+            assert p.start_ns <= s.start_ns <= s.end_ns <= p.end_ns
+        top = {s.name for s in r.spans if s.parent == 0}
+        assert {"runner.prompts", "engine.compile", "engine.prefill",
+                "engine.decode", "runner.fetch"} <= top
+        # siblings do not overlap: one thread, one span at a time
+        kids = sorted((s for s in r.spans if s.parent == 0),
+                      key=lambda s: s.start_ns)
+        assert all(a.end_ns <= b.start_ns for a, b in zip(kids, kids[1:]))
+    first, again, other = served_twice
+    assert [s.name for s in first.spans].count("runner.build") == 1
+    assert "runner.build" not in [s.name for s in again.spans]
+    assert "runner.build" in [s.name for s in other.spans]   # level 1 in
+    assert first.rid == again.rid == 7
+
+
+def test_share_seconds_are_their_spans(served_twice):
+    from repro.launch.serve import DECODE_STEPS
+
+    def dur(r, name):
+        s, = [s for s in r.spans if s.name == name]
+        return (s.end_ns - s.start_ns) * 1e-9
+    for r in served_twice:
+        names = [s.name for s in r.spans]
+        assert r.build_s == (dur(r, "runner.build")
+                             if "runner.build" in names else 0.0)
+        assert r.prefill_s == dur(r, "engine.prefill")
+        assert r.decode_step_s == dur(r, "engine.decode") / DECODE_STEPS
+        assert r.compile_s == {"prefill": dur(r, "engine.aot_prefill"),
+                               "decode": dur(r, "engine.aot_decode")}
+        assert r.prefill_s > 0 and r.decode_step_s > 0
+
+
+def test_compile_prefill_is_a_child_of_compile(served_twice):
+    for r in served_twice:
+        idx = {s.name: i for i, s in enumerate(r.spans)}
+        compile_i = idx["engine.compile"]
+        for child in ("engine.aot_prefill", "engine.compile_prefill",
+                      "engine.aot_decode"):
+            assert r.spans[idx[child]].parent == compile_i
+        assert r.spans[compile_i].parent == 0
+
+
+def test_compiles_counts_new_shapes_only(served_twice):
+    first, again, _ = served_twice
+    assert first.compiles >= 1
+    assert again.compiles == 0
+
+
+def test_record_without_collector_appends_nothing():
+    from repro.serving import spans
+    ran = []
+    with spans.record("outside"):
+        ran.append(1)
+    assert ran == [1]
+    with spans.collect("root") as got:
+        with spans.record("inner"):
+            ran.append(2)
+    assert [s.name for s in got] == ["root", "inner"]
+    assert [s.parent for s in got] == [None, 0]
+    with spans.record("after"):         # the collector closed with its block
+        ran.append(3)
+    assert ran == [1, 2, 3] and len(got) == 2
+
+
+def test_spans_of_another_thread_are_not_collected():
+    import threading
+    from repro.serving import spans
+
+    def elsewhere():
+        with spans.record("other.thread"):
+            pass
+    with spans.collect("root") as got:
+        t = threading.Thread(target=elsewhere)
+        t.start()
+        t.join(timeout=10)
+    assert not t.is_alive()
+    assert [s.name for s in got] == ["root"]
+
+
+def test_compile_counter_counts_a_persistent_cache_load_once(tmp_path):
+    """A load from the persistent cache is one program, though JAX records
+    both its backend-compile event and a cache hit for it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from repro.serving import spans
+    f = jax.jit(lambda x: jnp.tanh(x) * 3.0)
+    x = jnp.ones((7, 5))
+    keys = ("jax_compilation_cache_dir", "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    try:
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+        cc.reset_cache()
+        n0 = spans.compiles()
+        f.lower(x).compile()
+        n1 = spans.compiles()
+        f.lower(x).compile()                 # in memory: nothing compiles
+        n2 = spans.compiles()
+        jax.clear_caches()
+        f.lower(x).compile()                 # loaded from the cache
+        n3 = spans.compiles()
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+    assert (n1 - n0, n2 - n1, n3 - n2) == (1, 0, 1)
+
+
+def test_programs_carry_their_function_names():
+    from repro.serving.engine import _init_program, _programs
+    cfg = get_smoke_config("phi4-mini-3.8b")
+    key = jax.random.PRNGKey(0)
+    init = _init_program(cfg)
+    assert init.lower(key).as_text().startswith("module @jit_init_params")
+    params = init(key)
+    prefill, decode = _programs(cfg, False, True)
+    tokens = jnp.ones((2, 16), jnp.int32)
+    assert prefill.lower(params, tokens, None).as_text().startswith(
+        "module @jit_prefill")
+    eng = Engine(cfg, params, EngineConfig(max_len=32))
+    logits, caches, lengths = eng.prefill(tokens)
+    tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    assert decode.lower(params, caches, lengths, tok).as_text().startswith(
+        "module @jit_decode_step")
+
+
+def test_a_swap_releases_outside_build(served_twice):
+    """``build_s`` is the weight draw alone: freeing the resident level is
+    ``runner.release``, a span of its own just before ``runner.build``."""
+    first, again, other = served_twice
+    assert "runner.release" not in [s.name for s in first.spans]
+    assert "runner.release" not in [s.name for s in again.spans]
+    rel, = [s for s in other.spans if s.name == "runner.release"]
+    build, = [s for s in other.spans if s.name == "runner.build"]
+    assert rel.parent == build.parent == 0
+    assert rel.end_ns <= build.start_ns
+    assert other.build_s == build.seconds
