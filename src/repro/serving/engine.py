@@ -129,6 +129,7 @@ class Engine:
         self.params = jax.device_put(params, self.device)
         self._prefill, self._decode = _programs(cfg, ecfg.use_kernels,
                                                 ecfg.donate_cache)
+        self._decode_shapes = {}    # (tokens shape, dtype) -> _decode_args
 
     def prefill(self, tokens: jax.Array, embeds: Optional[jax.Array] = None):
         tokens = jax.device_put(tokens, self.device)
@@ -147,23 +148,39 @@ class Engine:
 
     def compile(self, tokens: jax.Array) -> None:
         """Compile prefill and decode for this batch shape ahead of serving
-        (a later call with the same shapes reuses the executables). Runs one
-        prefill to get the decode cache it compiles against. Spans:
+        (a later call with the same shapes reuses the executables). Runs no
+        prefill and leaves nothing on the device: decode compiles against
+        the shapes of the cache, lengths and token that ``prefill(tokens)``
+        and its argmax would give, on this engine's device as the served
+        arrays are, so the caller's own prefill is the only one. Spans:
         ``engine.compile`` around it all; inside, ``engine.aot_prefill`` and
         ``engine.aot_decode`` around each program's compile (near 0 when it
-        is already compiled) and ``engine.compile_prefill`` around the
-        extra prefill."""
+        is already compiled)."""
         tokens = jax.device_put(tokens, self.device)
         with record("engine.compile"):
             with record("engine.aot_prefill"):
                 self._prefill.lower(self.params, tokens, None).compile()
-            with record("engine.compile_prefill"):
-                logits, caches, lengths = self.prefill(tokens)
-                # finish it here, or it runs into the caller's next timed call
-                jax.block_until_ready((logits, caches))
-            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             with record("engine.aot_decode"):
-                self._decode.lower(self.params, caches, lengths, tok).compile()
+                self._decode.lower(self.params,
+                                   *self._decode_args(tokens)).compile()
+
+    def _decode_args(self, tokens: jax.Array):
+        """(caches, lengths, token) of the first decode step after
+        ``prefill(tokens)``, as shapes on this engine's device. Kept per
+        batch shape: tracing the prefill for them takes milliseconds."""
+        key = (tokens.shape, tokens.dtype)
+        if key not in self._decode_shapes:
+            def first_step(tokens):
+                logits, caches, lengths = self.prefill(tokens)
+                return caches, lengths, jnp.argmax(logits, axis=-1).astype(
+                    jnp.int32)
+            on_device = jax.sharding.SingleDeviceSharding(self.device)
+            self._decode_shapes[key] = jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=on_device,
+                                               weak_type=a.weak_type),
+                jax.eval_shape(first_step, tokens))
+        return self._decode_shapes[key]
 
     def release(self):
         """Free this engine's weights on its device now, so that the next

@@ -1,5 +1,8 @@
 """Serving engine tests: prefill/decode consistency against the full
 forward pass, ring-buffer invariants, generation."""
+import logging
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -235,14 +238,100 @@ def test_share_seconds_are_their_spans(served_twice):
         assert r.prefill_s > 0 and r.decode_step_s > 0
 
 
-def test_compile_prefill_is_a_child_of_compile(served_twice):
+def test_compile_holds_the_two_aot_compiles_only(served_twice):
+    """``engine.compile`` runs no prefill: its children are the two
+    programs' compiles, in order, and nothing else."""
     for r in served_twice:
-        idx = {s.name: i for i, s in enumerate(r.spans)}
-        compile_i = idx["engine.compile"]
-        for child in ("engine.aot_prefill", "engine.compile_prefill",
-                      "engine.aot_decode"):
-            assert r.spans[idx[child]].parent == compile_i
+        compile_i, = [i for i, s in enumerate(r.spans)
+                      if s.name == "engine.compile"]
         assert r.spans[compile_i].parent == 0
+        assert [s.name for s in r.spans if s.parent == compile_i] == [
+            "engine.aot_prefill", "engine.aot_decode"]
+        assert "engine.compile_prefill" not in [s.name for s in r.spans]
+
+
+class _CompileLog(logging.Handler):
+    """Names of the served programs JAX lowers or compiles while
+    ``jax_log_compiles`` is on (where a program was lowered before, for
+    another device, JAX logs only its compile)."""
+    PROGRAM = re.compile(
+        r"(?:Compiling|XLA compilation of) jit\((prefill|decode_step)\)")
+
+    def __init__(self):
+        super().__init__()
+        self.programs = []
+
+    def emit(self, record):
+        m = self.PROGRAM.search(record.getMessage())
+        if m:
+            self.programs.append(m.group(1))
+
+
+def _serve_engine(eng, tokens, steps):
+    """A share's engine work: one prefill, then ``steps`` greedy steps.
+    Returns the prefill's logits and the (B, steps) tokens."""
+    logits, caches, lengths = eng.prefill(tokens)
+    first, out = logits, []
+    for _ in range(steps):
+        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        out.append(tok)
+        logits, caches, lengths = eng.decode(caches, lengths, tok)
+    return np.asarray(first, np.float32), np.stack(
+        [np.asarray(t) for t in out], axis=1)
+
+
+@pytest.mark.parametrize("device_index", [0, 1])
+def test_compile_leaves_the_served_calls_nothing_to_compile(device_index):
+    """After ``Engine.compile(tokens)`` a share's prefill and decode steps
+    on the engine's device compile neither program: the abstract decode
+    arguments carry that device, as the served arrays do. A shape served
+    without ``compile`` shows that the log names both programs."""
+    from repro.serving.engine import init_params_on
+    if len(jax.devices()) <= device_index:
+        pytest.skip(f"the host has no device {device_index}")
+    device = jax.devices()[device_index]
+    cfg = get_smoke_config("phi4-mini-3.8b")
+    params = init_params_on(cfg, jax.random.PRNGKey(5), device)
+    eng = Engine(cfg, params, EngineConfig(max_len=72), device=device)
+    compiled, fresh = (jax.device_put(jnp.ones((5, 37), jnp.int32), device),
+                       jax.device_put(jnp.ones((3, 37), jnp.int32), device))
+    eng.compile(compiled)
+    log = _CompileLog()
+    logger = logging.getLogger("jax")
+    logger.addHandler(log)
+    try:
+        with jax.log_compiles(True):
+            _serve_engine(eng, compiled, 3)
+            after_compile = list(log.programs)
+            _serve_engine(eng, fresh, 3)
+    finally:
+        logger.removeHandler(log)
+    eng.release()
+    assert after_compile == []
+    assert set(log.programs) == {"prefill", "decode_step"}
+
+
+def test_share_is_a_plain_prefill_and_decode(served_twice):
+    """A served share's logits and tokens are exactly those of
+    ``Engine.prefill`` and ``DECODE_STEPS`` greedy decode steps, with no
+    ``compile`` first, on the same prompts and weights."""
+    from repro.launch.serve import (CACHE_LEN, DECODE_STEPS, ShareRunner,
+                                    place_nodes)
+    from repro.serving.engine import init_params_on
+    first = served_twice[0]
+    device = jax.devices()[0]
+    runner = ShareRunner(get_smoke_config("phi4-mini-3.8b"),
+                         place_nodes(["n0", "n1"], [device]))
+    cfg = runner.pool[first.level].config
+    tokens = runner._prompts(first.rid, first.node, first.served,
+                             cfg.vocab_size)
+    params = init_params_on(cfg, jax.random.fold_in(
+        jax.random.PRNGKey(0), first.level), device)
+    eng = Engine(cfg, params, EngineConfig(max_len=CACHE_LEN), device=device)
+    logits, toks = _serve_engine(eng, tokens, DECODE_STEPS)
+    eng.release()
+    np.testing.assert_array_equal(first.logits, logits)
+    np.testing.assert_array_equal(first.tokens, toks)
 
 
 def test_compiles_counts_new_shapes_only(served_twice):

@@ -79,5 +79,6 @@ def test_rows_of_a_smoke_run_split_without_remainder_below_zero():
                                 plan_s=0.0, run_s=run_s, shares=shares)
     got = tool.per_request(tool._row(rec, traced=False))
     assert got["runner.release"] > 0                 # level 1 took level 0's place
-    assert got["engine.compile_prefill"] > 0
+    assert got["engine.aot_decode"] > 0
+    assert "engine.compile_prefill" not in got       # compile runs no prefill
     assert 0 <= got["between spans"] < got["runner_untimed_s"] < run_s
