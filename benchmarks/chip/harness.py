@@ -66,27 +66,36 @@ def find_cell(bench: Dict, name: str) -> Cell:
                 end_to_end=[m for m in bench["end_to_end"] if mine(m)])
 
 
-def check_served_config(doc: Dict, cfg) -> None:
-    """The program has to serve what the configuration file states: the
-    model's sizes and every level of its ladder."""
-    ref = importlib.import_module(f"reference.{doc['reference']}")
-    bad = []
-    for key, want in ref.served_config(doc).items():
+def _mismatches(cfg, want: Dict, where: str = "") -> List[str]:
+    """Dotted names of ``cfg`` (``"moe.top_k"``) whose values are not
+    ``want``'s."""
+    out = []
+    for key, value in want.items():
         got = cfg
         for part in key.split("."):
             got = getattr(got, part)
-        if got != want:
-            bad.append(f"{key}: program {got!r}, file {want!r}")
+        if got != value:
+            out.append(f"{where}{key}: program {got!r}, file {value!r}")
+    return out
+
+
+def check_served_config(doc: Dict, cfg) -> None:
+    """The program has to serve what the configuration file states: the
+    model's sizes and every level of its ladder, as the file's reference
+    reads them (``served_config``, ``served_ladder``: dotted names of the
+    program's config and the values they must hold)."""
+    ref = importlib.import_module(f"reference.{doc['reference']}")
+    bad = _mismatches(cfg, ref.served_config(doc))
     pool = entry.VariantPool(cfg)
     if len(pool) != len(doc["ladder"]):
         bad.append(f"ladder: program {len(pool)} levels, file "
                    f"{len(doc['ladder'])}")
     for v, lv in zip(pool.variants, doc["ladder"]):
-        got = (v.config.d_ff, v.config.num_layers)
-        want = (lv["intermediate_size"], lv["num_hidden_layers"])
-        if got != want or abs(v.accuracy - lv["accuracy"]) > 1e-9:
-            bad.append(f"level {v.level}: program {got} {v.accuracy}, "
-                       f"file {want} {lv['accuracy']}")
+        bad += _mismatches(v.config, ref.served_ladder(doc, v.level),
+                           f"level {v.level} ")
+        if abs(v.accuracy - lv["accuracy"]) > 1e-9:
+            bad.append(f"level {v.level} accuracy: program {v.accuracy}, "
+                       f"file {lv['accuracy']}")
     if bad:
         raise SystemExit("the program's config differs from "
                          f"{doc['arch']}'s file: " + "; ".join(bad))
@@ -203,22 +212,13 @@ def _checks_line(checks: Dict[str, Dict]) -> None:
 
 def weights_differing(doc: Dict, runner) -> Dict[int, int]:
     """Elements in which the weights the reference draws differ from the
-    ones each chip serves at its resident level (0 expected)."""
-    import jax
+    ones each chip serves at its resident level (0 expected), over every
+    group of parameters the reference names."""
     ref = importlib.import_module(f"reference.{doc['reference']}")
     out = {}
     for level, eng in runner.resident.values():
-        lw = ref.weights(doc, level)
-        bad = sum(int(np.sum(np.asarray(v) != np.asarray(
-            eng.params["embed"][k], np.float32)))
-            for k, v in lw.embed().items())
-        flat = jax.tree_util.tree_flatten_with_path(eng.params["layers"])[0]
-        for i in range(lw.n_layers):
-            w = lw.layer(i)
-            for path, leaf in flat:
-                name = "/".join(p.key for p in path)
-                bad += int(np.sum(np.asarray(w[name]) != np.asarray(
-                    leaf[i], np.float32)))
+        bad = sum(int(np.sum(drawn != served)) for _, drawn, served in
+                  ref.weights(doc, level).against(eng.params))
         out[int(level)] = out.get(int(level), 0) + bad
     return out
 

@@ -13,9 +13,9 @@ def read(ctx):
     bound, secs, kinds = 0.0, 0.0, set()
     for r in ctx.records:
         for s in r.shares:
-            z = counts.sizes(ctx.doc, s.level)
+            c = counts.of(ctx.doc, s.level)
             steps = [counts.decode_bound_s(
-                z, s.served, ctx.prompt_len + 1 + i, ctx.peaks.bf16_flops,
+                c, s.served, ctx.prompt_len + 1 + i, ctx.peaks.bf16_flops,
                 ctx.peaks.hbm_bytes_per_s) for i in range(ctx.decode_steps)]
             bound += sum(b["s"] for b in steps) / len(steps)
             kinds.update(b["bound"] for b in steps)

@@ -1,6 +1,6 @@
 """Engine: the prefill's share (%) of the chip's bf16 peak: the operations
-the prefills of the window need (``counts.prefill_flops``) over the
-runner's prefill seconds, each share on one chip."""
+the prefills of the window need (``prefill_flops`` of each level's
+``counts.of``) over the runner's prefill seconds, each share on one chip."""
 import counts
 
 
@@ -11,7 +11,7 @@ def read(ctx):
     secs = sum(s.prefill_s for s in shares)
     if not shares or secs <= 0:
         return None
-    flops = sum(counts.prefill_flops(counts.sizes(ctx.doc, s.level),
-                                     s.served, ctx.prompt_len)
+    flops = sum(counts.of(ctx.doc, s.level).prefill_flops(s.served,
+                                                          ctx.prompt_len)
                 for s in shares)
     return 100.0 * flops / secs / ctx.peaks.bf16_flops

@@ -1,7 +1,6 @@
 """Share runner: seconds per request inside ``runner.run`` outside the
-runner's own timed parts (weight build, prefill, decode steps): the compile
-step's extra prefill, prompt generation, host copies. Mean over the
-window's requests."""
+runner's own timed parts (weight build, prefill, decode steps): compiles,
+prompt generation, host copies. Mean over the window's requests."""
 
 
 def read(ctx):

@@ -14,9 +14,8 @@ def read(ctx):
     flops = 0.0
     for r in ctx.traced:
         for s in r.shares:
-            z = counts.sizes(ctx.doc, s.level)
-            flops += counts.prefill_flops(z, s.served, ctx.prompt_len)
-            flops += sum(counts.decode_flops(z, s.served,
-                                             ctx.prompt_len + 1 + i)
+            c = counts.of(ctx.doc, s.level)
+            flops += c.prefill_flops(s.served, ctx.prompt_len)
+            flops += sum(c.decode_flops(s.served, ctx.prompt_len + 1 + i)
                          for i in range(ctx.decode_steps - 1))
     return 100.0 * flops / (t.window_s * ctx.chips * ctx.peaks.bf16_flops)

@@ -11,8 +11,10 @@ from typing import Dict, List
 import jax
 import jax.numpy as jnp
 
+import counts as counts_mod
+
 from . import numerics as nx
-from .weights import LevelWeights
+from .weights import Group, LevelWeights
 
 
 def served_config(doc: Dict) -> Dict:
@@ -31,6 +33,34 @@ def served_config(doc: Dict) -> Dict:
             "attn_logit_softcap": 0.0, "final_logit_softcap": 0.0,
             "moe": None, "frontend_stub": False,
             "dtype": doc["torch_dtype"]}
+
+
+def served_ladder(doc: Dict, level: int) -> Dict:
+    """What the program's config of ladder level ``level`` has to say."""
+    lv = doc["ladder"][level]
+    return {"d_ff": lv["intermediate_size"],
+            "num_layers": lv["num_hidden_layers"]}
+
+
+def counts(doc: Dict, level: int) -> counts_mod.Sizes:
+    """The operations and bytes of ladder level ``level`` (``counts.py``)."""
+    lv = doc["ladder"][level]
+    return counts_mod.Sizes(
+        mixer="gqa", mlp={"silu": "swiglu"}.get(doc["hidden_act"], "gelu"),
+        layers=lv["num_hidden_layers"], d_model=doc["hidden_size"],
+        d_ff=lv["intermediate_size"], vocab=doc["vocab_size"],
+        heads=doc["num_attention_heads"],
+        kv_heads=doc["num_key_value_heads"], head_dim=doc["head_dim"],
+        tied=doc["tie_word_embeddings"])
+
+
+def smoke_sizes(doc: Dict, cfg) -> Dict:
+    """The file's sizes for the program's model config ``cfg`` (the CPU
+    tests put the program's smoke sizes in the file with it)."""
+    return dict(hidden_size=cfg.d_model, num_hidden_layers=cfg.num_layers,
+                num_attention_heads=cfg.num_heads,
+                num_key_value_heads=cfg.num_kv_heads, head_dim=cfg.head_dim,
+                intermediate_size=cfg.d_ff, vocab_size=cfg.vocab_size)
 
 
 def weights(doc: Dict, level: int) -> LevelWeights:
@@ -54,7 +84,10 @@ def weights(doc: Dict, level: int) -> LevelWeights:
     embed = [(("embedding",), (v, d), "normal")]
     if not doc["tie_word_embeddings"]:
         embed.append((("lm_head",), (d, v), "normal"))
-    return LevelWeights(doc, level, embed, layer, lv["num_hidden_layers"])
+    return LevelWeights(doc, level, [
+        Group("embed", tuple(embed)),
+        Group("final_norm", (((), (d,), "ones"),)),
+        Group("layers", tuple(layer), lv["num_hidden_layers"])])
 
 
 def _rope(x, theta):

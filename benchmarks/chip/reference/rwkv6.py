@@ -13,8 +13,10 @@ from typing import Dict, List
 import jax
 import jax.numpy as jnp
 
+import counts as counts_mod
+
 from . import numerics as nx
-from .weights import LevelWeights
+from .weights import Group, LevelWeights
 
 
 def served_config(doc: Dict) -> Dict:
@@ -28,6 +30,34 @@ def served_config(doc: Dict) -> Dict:
             "pos_kind": "none", "zero_centered_norm": False,
             "final_logit_softcap": 0.0, "frontend_stub": False,
             "dtype": doc["torch_dtype"]}
+
+
+def served_ladder(doc: Dict, level: int) -> Dict:
+    """What the program's config of ladder level ``level`` has to say."""
+    lv = doc["ladder"][level]
+    return {"d_ff": lv["intermediate_size"],
+            "num_layers": lv["num_hidden_layers"]}
+
+
+def counts(doc: Dict, level: int) -> counts_mod.Sizes:
+    """The operations and bytes of ladder level ``level`` (``counts.py``)."""
+    lv = doc["ladder"][level]
+    hs = doc["head_size"]
+    return counts_mod.Sizes(
+        mixer="wkv", mlp="channel_mix", layers=lv["num_hidden_layers"],
+        d_model=doc["hidden_size"], d_ff=lv["intermediate_size"],
+        vocab=doc["vocab_size"], heads=doc["attention_hidden_size"] // hs,
+        kv_heads=doc["attention_hidden_size"] // hs, head_dim=hs,
+        tied=doc["tie_word_embeddings"], lora=doc["time_decay_extra_dim"])
+
+
+def smoke_sizes(doc: Dict, cfg) -> Dict:
+    """The file's sizes for the program's model config ``cfg`` (the CPU
+    tests put the program's smoke sizes in the file with it)."""
+    return dict(hidden_size=cfg.d_model, num_hidden_layers=cfg.num_layers,
+                attention_hidden_size=cfg.d_model,
+                head_size=cfg.ssm.wkv_head_dim, intermediate_size=cfg.d_ff,
+                vocab_size=cfg.vocab_size)
 
 
 def weights(doc: Dict, level: int) -> LevelWeights:
@@ -60,7 +90,10 @@ def weights(doc: Dict, level: int) -> LevelWeights:
     embed = [(("embedding",), (doc["vocab_size"], d), "normal")]
     if not doc["tie_word_embeddings"]:
         embed.append((("lm_head",), (d, doc["vocab_size"]), "normal"))
-    return LevelWeights(doc, level, embed, layer, lv["num_hidden_layers"])
+    return LevelWeights(doc, level, [
+        Group("embed", tuple(embed)),
+        Group("final_norm", (((), (d,), "ones"),)),
+        Group("layers", tuple(layer), lv["num_hidden_layers"])])
 
 
 def _shift(x):

@@ -4,6 +4,8 @@
 from __future__ import annotations
 
 import copy
+import glob
+import importlib
 import json
 import os
 import sys
@@ -16,33 +18,47 @@ if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 
 import entry  # noqa: E402
+from repro.configs import ARCH_NAMES, get_smoke_config  # noqa: E402
 
 
-def smoke_doc(arch: str) -> dict:
-    """The configuration file of ``arch`` with the program's smoke sizes in
-    place of the published ones, and the smoke ladder."""
-    from repro.configs import get_smoke_config
+def _config_files() -> dict:
+    """Each arch's configuration file: ``configs/<arch>.json`` where there
+    is one, else the first by name that serves the arch."""
+    files = {}
+    for path in sorted(glob.glob(os.path.join(BENCH, "configs", "*.json"))):
+        with open(path) as f:
+            arch = json.load(f)["arch"]
+        if os.path.basename(path) == f"{arch}.json" or arch not in files:
+            files[arch] = path
+    return files
+
+
+CONFIG_FILES = _config_files()
+
+
+def smoke_doc(arch: str, doc: dict | None = None) -> dict:
+    """The configuration file of ``arch`` (or ``doc``) with the program's
+    smoke sizes in place of the published ones, as its reference names
+    them (``smoke_sizes``), and the smoke ladder under the keys of the
+    file's own ladder."""
     smoke = get_smoke_config(arch)
-    with open(os.path.join(BENCH, "configs", f"{arch}.json")) as f:
-        doc = copy.deepcopy(json.load(f))
-    if doc["reference"] == "dense_gqa":
-        doc.update(hidden_size=smoke.d_model, num_hidden_layers=smoke.num_layers,
-                   num_attention_heads=smoke.num_heads,
-                   num_key_value_heads=smoke.num_kv_heads,
-                   head_dim=smoke.head_dim, intermediate_size=smoke.d_ff,
-                   vocab_size=smoke.vocab_size)
-    else:
-        doc.update(hidden_size=smoke.d_model, num_hidden_layers=smoke.num_layers,
-                   attention_hidden_size=smoke.d_model,
-                   head_size=smoke.ssm.wkv_head_dim,
-                   intermediate_size=smoke.d_ff, vocab_size=smoke.vocab_size)
-    pool = entry.VariantPool(smoke)
-    doc["ladder"] = [{"level": v.level, "intermediate_size": v.config.d_ff,
-                      "num_hidden_layers": v.config.num_layers,
-                      "accuracy": v.accuracy} for v in pool.variants]
+    if doc is None:
+        with open(CONFIG_FILES[arch]) as f:
+            doc = json.load(f)
+    doc = copy.deepcopy(doc)
+    ref = importlib.import_module(f"reference.{doc['reference']}")
+    doc.update(ref.smoke_sizes(doc, smoke))
+    keys = [k for k in doc["ladder"][0] if k not in ("level", "accuracy")]
+    ladder = []
+    for v in entry.VariantPool(smoke).variants:
+        sizes = ref.smoke_sizes(doc, v.config)
+        ladder.append({"level": v.level, **{k: sizes[k] for k in keys},
+                       "accuracy": v.accuracy})
+    doc["ladder"] = ladder
     return doc
 
 
-@pytest.fixture(params=["phi4-mini-3.8b", "rwkv6-1.6b"])
+@pytest.fixture(params=sorted(a for a in CONFIG_FILES if a in ARCH_NAMES))
 def arch(request):
+    """Every arch a configuration file serves, once each."""
     return request.param

@@ -3,6 +3,7 @@ weights they draw again are the served ones, bit for bit; in float32 they
 compute what the program computes; the float8 control reads far from the
 bf16 program."""
 import dataclasses
+import importlib
 import itertools
 
 import compare
@@ -13,13 +14,14 @@ import numpy as np
 import pytest
 import traffic
 from conftest import smoke_doc
-from reference import dense_gqa, rwkv6
 
 from repro.configs import get_smoke_config
 from repro.models import model as model_lib
 from repro.models import transformer as tfm
 
-REFS = {"phi4-mini-3.8b": dense_gqa, "rwkv6-1.6b": rwkv6}
+
+def _ref(doc):
+    return importlib.import_module(f"reference.{doc['reference']}")
 
 
 def _program(arch, dtype, level):
@@ -36,18 +38,10 @@ def _program(arch, dtype, level):
 def test_weights_are_the_served_ones(arch, level):
     doc = smoke_doc(arch)
     _, params = _program(arch, "bfloat16", level)
-    lw = REFS[arch].weights(doc, level)
-    for k, v in lw.embed().items():
-        np.testing.assert_array_equal(
-            v, np.asarray(params["embed"][k], np.float32))
-    flat = jax.tree_util.tree_flatten_with_path(params["layers"])[0]
-    for i in range(lw.n_layers):
-        w = lw.layer(i)
-        assert len(w) == len(flat)
-        for path, leaf in flat:
-            name = "/".join(p.key for p in path)
-            np.testing.assert_array_equal(w[name],
-                                          np.asarray(leaf[i], np.float32))
+    lw = _ref(doc).weights(doc, level)
+    assert set(lw.groups) == set(params)        # every group
+    for where, drawn, served in lw.against(params):
+        np.testing.assert_array_equal(drawn, served, err_msg=where)
 
 
 def test_float32_reference_is_the_program(arch):
@@ -57,7 +51,7 @@ def test_float32_reference_is_the_program(arch):
     toks = np.random.default_rng(0).integers(0, doc["vocab_size"], (3, 24),
                                              dtype=np.int32)
     got, _ = jax.jit(lambda p, t: model_lib.forward(vcfg, p, t))(params, toks)
-    want = REFS[arch].logits(doc, 0, [toks], last=4)[0]
+    want = _ref(doc).logits(doc, 0, [toks], last=4)[0]
     err = np.abs(np.asarray(got[:, -4:]) - want).max() / np.abs(want).max()
     assert err < 1e-5
 

@@ -1,6 +1,6 @@
-"""The readers of the program's spans and compile counter
-(``compile_prefill_s``, ``window_compiles``) on hand-made records and on a
-smoke-config run, and idle gaps named by the program's spans."""
+"""The reader of the program's compile counter (``window_compiles``) on
+hand-made records and on a smoke-config run, the program's spans on that
+run, and idle gaps named by the program's spans."""
 import contextlib
 import importlib
 import types
@@ -17,7 +17,7 @@ from repro.configs import get_smoke_config
 from repro.serving.spans import Span
 
 MS = 1_000_000
-READERS = ("compile_prefill_s", "window_compiles")
+READERS = ("window_compiles",)
 
 
 def _read(name, records):
@@ -25,13 +25,9 @@ def _read(name, records):
     return importlib.import_module(f"metrics.{name}").read(ctx)
 
 
-def _share(start_ms, end_ms, compile_prefill_ms=0, compiles=0):
-    s, e = start_ms * MS, end_ms * MS
-    spans = (Span("runner.share", s, e, None),
-             Span("engine.compile", s, s + 2 * compile_prefill_ms * MS, 0),
-             Span("engine.compile_prefill", s + MS,
-                  s + (1 + compile_prefill_ms) * MS, 1))
-    return types.SimpleNamespace(spans=spans, compiles=compiles)
+def _share(compiles=0):
+    return types.SimpleNamespace(spans=(Span("runner.share", 0, MS, None),),
+                                 compiles=compiles)
 
 
 def _record(*shares):
@@ -39,12 +35,8 @@ def _record(*shares):
 
 
 def test_readers_on_hand_made_records():
-    serial = _record(_share(0, 10, 3, compiles=2), _share(10, 30, 5))
-    overlap = _record(_share(100, 120, 4), _share(100, 120, 4),
-                      _share(105, 125, 4), _share(110, 120, 4, compiles=1))
-    recs = [serial, overlap]
-    # (3 + 5) + 4 * 4 ms over two requests
-    assert _read("compile_prefill_s", recs) == pytest.approx(0.012)
+    recs = [_record(_share(2), _share()),
+            _record(_share(), _share(), _share(), _share(1))]
     assert _read("window_compiles", recs) == pytest.approx(1.5)
 
 
@@ -58,8 +50,9 @@ def test_readers_find_nothing_without_spans():
 
 
 def test_readers_on_a_smoke_run():
-    """Warm up a dispatch, then serve it again as the window: the extra
-    prefill shows in its spans and nothing compiles."""
+    """Warm up a dispatch, then serve it again as the window: nothing
+    compiles, and no share runs an extra prefill to compile its decode
+    step (no ``engine.compile_prefill`` span)."""
     arch = "phi4-mini-3.8b"
     cfg, doc = get_smoke_config(arch), smoke_doc(arch)
     gn = entry.build_gateway(cfg, policy=doc["policy"])
@@ -78,21 +71,15 @@ def test_readers_on_a_smoke_run():
                              lambda name: contextlib.nullcontext())
     window = [serve(), serve()]
     runner.close()
-    shares = [s for r in window for s in r.shares]
-    extra = sum(sp.end_ns - sp.start_ns for s in shares for sp in s.spans
-                if sp.name == "engine.compile_prefill") * 1e-9
-    assert _read("compile_prefill_s", window) == pytest.approx(extra / 2)
-    assert extra > 0
+    names = {sp.name for r in window for s in r.shares for sp in s.spans}
+    assert {"runner.share", "engine.prefill", "engine.decode"} <= names
+    assert "engine.compile_prefill" not in names
     assert _read("window_compiles", window) == 0
-    # the extra prefill lies outside the runner's other timed parts, so
-    # with them it fits inside runner.run
+    # the runner's timed parts fit inside runner.run
     for r in window:
         timed = sum(s.build_s + s.prefill_s + entry.DECODE_STEPS *
                     s.decode_step_s for s in r.shares)
-        extra = sum(sp.end_ns - sp.start_ns for s in r.shares
-                    for sp in s.spans
-                    if sp.name == "engine.compile_prefill") * 1e-9
-        assert timed + extra <= r.run_s
+        assert timed <= r.run_s
 
 
 def test_idle_gap_is_named_by_the_program_span():
