@@ -160,7 +160,7 @@ def phase_check(cfg, device) -> None:
         for prog in ("prefill", "decode"):
             s = spans.seconds(got, f"engine.aot_{prog}")
             print(f"compile {name} {prog}: {s:.3f} s", flush=True)
-        (logits, caches, lengths), s = _timed(lambda: eng.prefill(tokens))
+        (logits, caches, lengths, _), s = _timed(lambda: eng.prefill(tokens))
         print(f"{name} prefill B={CHECK_BATCH} S={PROMPT_LEN}: {s:.4f} s",
               flush=True)
         out[name] = (logits, caches, lengths)

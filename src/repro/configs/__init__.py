@@ -15,6 +15,7 @@ _ARCH_MODULES = {
     "musicgen-medium": "musicgen_medium",
     "mixtral-8x7b": "mixtral_8x7b",
     "deepseek-v3-671b": "deepseek_v3_671b",
+    "deepseek-v3-ep32": "deepseek_v3_ep32",
     "llava-next-mistral-7b": "llava_next_mistral_7b",
 }
 

@@ -23,6 +23,23 @@ class MoEConfig:
     first_moe_layer: int = 0
     moe_every: int = 1
     router_scale: float = 1.0        # routed-expert output scaling (deepseek 2.5)
+    # routing: "softmax" over all experts, or "sigmoid" scores with a
+    # correction bias, top-k within the ``topk_group`` best of ``n_group``
+    # groups (deepseek-v3's noaux_tc)
+    scoring: str = "softmax"
+    n_group: int = 1
+    topk_group: int = 1
+    # experts this layer holds, [0, held_experts): its share of an expert-
+    # parallel deployment; 0 holds all of them
+    held_experts: int = 0
+    # draw each expert matrix with std 1/sqrt(its input width), as a dense
+    # matrix draws; False: 1/sqrt(the expert count), what the init gives a
+    # stack of experts by default
+    expert_init_by_width: bool = True
+
+    @property
+    def held(self) -> int:
+        return self.held_experts or self.num_experts
 
 
 @dataclass(frozen=True)
@@ -168,7 +185,7 @@ class ModelConfig:
             p += d * (m.kv_lora_rank + m.qk_rope_head_dim)
             p += m.kv_lora_rank * self.num_heads * (m.qk_nope_head_dim + m.v_head_dim)
             p += self.num_heads * m.v_head_dim * d
-            return p
+            return p + m.q_lora_rank + m.kv_lora_rank   # and their norms
         return d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
 
     def _mlp_params(self, d_ff: int) -> int:
@@ -202,9 +219,11 @@ class ModelConfig:
             p += self._mlp_params(self.d_ff_dense or self.d_ff)
         elif self.layer_is_moe(i):
             m = self.moe
-            n_routed = m.top_k if active_only else m.num_experts
+            n_routed = m.top_k if active_only else m.held
             p += (n_routed + m.num_shared_experts) * self._mlp_params(m.d_ff_expert)
             p += d * m.num_experts  # router
+            if m.scoring == "sigmoid":
+                p += m.num_experts  # its correction bias
         else:
             p += self._mlp_params(self.d_ff)
         return p

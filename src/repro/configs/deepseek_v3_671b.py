@@ -3,6 +3,8 @@
 
 First 3 layers are dense (d_ff 18432); the remaining 58 are MoE with
 per-expert d_ff 2048 (the assigned "d_ff=2048" is the expert hidden dim).
+Routing as published (``noaux_tc``): sigmoid scores, 8 groups of 32
+experts, the top-8 within the best 4 groups, renormalised, times 2.5.
 """
 from repro.configs.base import ModelConfig, MoEConfig, MLAConfig
 
@@ -23,7 +25,8 @@ CONFIG = ModelConfig(
                   qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128),
     moe=MoEConfig(num_experts=256, top_k=8, d_ff_expert=2048,
                   num_shared_experts=1, first_moe_layer=3, moe_every=1,
-                  router_scale=2.5),
+                  router_scale=2.5, scoring="sigmoid", n_group=8,
+                  topk_group=4),
     num_dense_layers=3,
     d_ff_dense=18432,
     mtp_depth=1,
@@ -34,9 +37,12 @@ SMOKE = CONFIG.scaled(
     d_ff=64, vocab_size=256,
     mla=MLAConfig(q_lora_rank=32, kv_lora_rank=16,
                   qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16),
-    moe=MoEConfig(num_experts=8, top_k=2, d_ff_expert=64,
+    # routes by softmax and draws its experts by their count, so its
+    # parameters are the ones the yardstick's toy MLA/MoE reference names
+    # and draws; deepseek-v3-ep32's smoke routes and draws as published
+    moe=MoEConfig(num_experts=8, top_k=2, d_ff_expert=256,
                   num_shared_experts=1, first_moe_layer=1, moe_every=1,
-                  router_scale=2.5),
+                  router_scale=2.5, expert_init_by_width=False),
     num_dense_layers=1,
     d_ff_dense=128,
     mtp_depth=1,

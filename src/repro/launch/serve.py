@@ -87,6 +87,10 @@ class ShareResult:
     rid: Optional[int] = None  # the request's id
     spans: Tuple[spans.Span, ...] = ()   # ``runner.share`` first
     compiles: int = 0          # programs compiled or loaded during the share
+    # configs with experts: rows the prefill routed to held experts, and
+    # rows its grouped matmuls were given, summed over the expert layers
+    expert_rows: int = 0
+    expert_slots: int = 0
 
 
 class ShareRunner:
@@ -152,7 +156,7 @@ class ShareRunner:
             eng.compile(tokens)
 
             with spans.record("engine.prefill"):
-                logits, caches, lengths = eng.prefill(tokens)
+                logits, caches, lengths, counts = eng.prefill(tokens)
                 jax.block_until_ready((logits, caches))
 
             first = logits
@@ -167,6 +171,7 @@ class ShareRunner:
             with spans.record("runner.fetch"):
                 first = np.asarray(first, np.float32)
                 out = np.stack([np.asarray(t) for t in out], axis=1)
+                rows, slots = (0, 0) if counts is None else np.asarray(counts)
         got = tuple(got)
         return ShareResult(
             node=a.node, level=a.apx_level, device=str(device), items=a.items,
@@ -176,7 +181,8 @@ class ShareRunner:
                        "decode": spans.seconds(got, "engine.aot_decode")},
             prefill_s=spans.seconds(got, "engine.prefill"),
             decode_step_s=spans.seconds(got, "engine.decode") / DECODE_STEPS,
-            rid=rid, spans=got, compiles=spans.compiles() - compiled)
+            rid=rid, spans=got, compiles=spans.compiles() - compiled,
+            expert_rows=int(rows), expert_slots=int(slots))
 
     def close(self):
         """Free every resident engine's weights."""

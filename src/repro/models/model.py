@@ -149,18 +149,23 @@ def loss_fn(cfg: ModelConfig, params, batch: Dict[str, jax.Array], *,
 # Serving paths
 def prefill(cfg: ModelConfig, params, tokens: jax.Array,
             embeds: Optional[jax.Array] = None, *, use_kernels: bool = False,
-            unroll: int | bool = 1) -> Tuple[jax.Array, Any]:
+            unroll: int | bool = 1) -> Tuple[jax.Array, ...]:
     """Process the prompt; returns (last-position logits, raw seq-length
-    caches). The engine pads these into max_len decode caches."""
+    caches), and for a config with experts a third entry: the rows routed
+    to held experts and the rows the grouped matmuls were given, summed over
+    the expert layers (float32, (2,)). The engine pads the caches into
+    max_len decode caches."""
     x = _embed_inputs(cfg, params, tokens, embeds)
     x = shard_activation(x, ("batch", "seq", None))
     positions = jnp.arange(x.shape[1])[None, :]
     caches_in = init_cache(cfg, x.shape[0], max_len=1, dtype=_dtype(cfg))
-    x, caches, _ = _backbone(cfg, params, x, positions, caches_in, None,
-                             mode="prefill", use_kernels=use_kernels,
-                             remat=False, unroll=unroll)
+    x, caches, counts = _backbone(cfg, params, x, positions, caches_in, None,
+                                  mode="prefill", use_kernels=use_kernels,
+                                  remat=False, unroll=unroll)
     logits = lm_logits(cfg, params["embed"], x[:, -1:])
-    return logits[:, 0], caches
+    if cfg.moe is None:
+        return logits[:, 0], caches
+    return logits[:, 0], caches, counts
 
 
 def decode_step(cfg: ModelConfig, params, caches, lengths: jax.Array,

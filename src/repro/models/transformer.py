@@ -7,7 +7,7 @@ params, keeping HLO size and compile time bounded at 512 devices:
   * homogeneous archs: one group, unit = 1 layer, n_units = L
   * gemma2: unit = (local layer, global layer), n_units = L/2
   * jamba: unit = 8 layers (attn at idx 3, rest mamba; MoE on odd idx)
-  * deepseek: group "dense" (3 units) + group "moe" (58 units)
+  * deepseek: group "dense_layers" (3 units) + group "layers" (58 units)
 """
 from __future__ import annotations
 
@@ -191,7 +191,9 @@ def _norm(cfg, scale, x):
 def sublayer_apply(cfg: ModelConfig, sl: SubLayer, p, x, positions,
                    cache, lengths, *, mode: str, use_kernels: bool):
     """mode: 'dense' (train, no cache out), 'prefill', 'decode'.
-    Returns (x, new_cache, aux_router_logits|None)."""
+    Returns (x, new_cache, aux): for an expert sublayer ``aux`` is its
+    router logits in dense mode and its expert row counts
+    (``moe_apply``) in prefill mode, else None."""
     aux = None
     h = _norm(cfg, p["norm_mixer"], x)
     if sl.mixer == "gqa":
@@ -247,9 +249,12 @@ def sublayer_apply(cfg: ModelConfig, sl: SubLayer, p, x, positions,
         x = x + out
     elif sl.mlp == "moe":
         h = _norm(cfg, p["norm_mlp"], x)
+        out, counts = moe_mod.moe_apply(cfg, p["moe"], h)
         if mode == "dense":  # collect router logits for aux loss
             aux = h.reshape(-1, cfg.d_model) @ p["moe"]["w_router"].astype(h.dtype)
-        x = x + moe_mod.moe_apply(cfg, p["moe"], h)
+        elif mode == "prefill":
+            aux = counts
+        x = x + out
     x = shard_activation(x, ("batch", "seq", None))
     return x, new_cache, aux
 
@@ -293,7 +298,9 @@ def group_apply(cfg: ModelConfig, group: Group, params_stacked, x, positions,
                 caches_stacked, lengths, *, mode: str, use_kernels: bool,
                 remat: bool = False, unroll: int | bool = 1,
                 remat_policy: str = "nothing"):
-    """Returns (x, new_caches_stacked, aux_sum).
+    """Returns (x, new_caches_stacked, aux_sum). ``aux_sum``: the
+    load-balance loss in dense mode; in prefill mode, for a group with
+    expert sublayers, their expert row counts (2,) summed over the group.
 
     ``unroll``: passed to lax.scan. The dry-run unrolls fully (unroll=True)
     because XLA's cost_analysis counts a while-loop body once regardless of
@@ -314,7 +321,8 @@ def group_apply(cfg: ModelConfig, group: Group, params_stacked, x, positions,
             if mode != "dense" and c_out is not None:
                 new_caches[f"sub{i}"] = c_out
             if aux is not None:
-                aux_sum = aux_sum + moe_mod.aux_load_balance_loss(cfg, aux)
+                aux_sum = aux_sum + (moe_mod.aux_load_balance_loss(cfg, aux)
+                                     if mode == "dense" else aux)
         return (x, aux_sum), (new_caches if mode != "dense" else 0.0)
 
     if remat:
@@ -324,8 +332,10 @@ def group_apply(cfg: ModelConfig, group: Group, params_stacked, x, positions,
             policy = jax.checkpoint_policies.nothing_saveable
         unit = jax.checkpoint(unit, policy=policy)
 
+    counted = mode == "prefill" and any(sl.mlp == "moe" for sl in group.pattern)
+    aux0 = jnp.zeros((2,), jnp.float32) if counted else jnp.float32(0.0)
     scanned = (params_stacked,) if caches_stacked is None else (
         params_stacked, caches_stacked)
-    (x, aux_sum), caches_out = jax.lax.scan(unit, (x, jnp.float32(0.0)),
+    (x, aux_sum), caches_out = jax.lax.scan(unit, (x, aux0),
                                             scanned, unroll=unroll)
     return x, (caches_out if mode != "dense" else None), aux_sum

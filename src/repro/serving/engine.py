@@ -132,15 +132,19 @@ class Engine:
         self._decode_shapes = {}    # (tokens shape, dtype) -> _decode_args
 
     def prefill(self, tokens: jax.Array, embeds: Optional[jax.Array] = None):
+        """Returns (logits, decode caches, lengths, expert_counts).
+        ``expert_counts``: for a config with experts, the rows routed to
+        held experts and the rows the grouped matmuls were given, summed
+        over its expert layers (a (2,) device array); else None."""
         tokens = jax.device_put(tokens, self.device)
         if embeds is not None:
             embeds = jax.device_put(embeds, self.device)
-        logits, raw = self._prefill(self.params, tokens, embeds)
+        logits, raw, *counts = self._prefill(self.params, tokens, embeds)
         seq_len = tokens.shape[1] + (embeds.shape[1] if embeds is not None else 0)
         caches = pad_caches(self.cfg, raw, seq_len, self.ecfg.max_len)
         lengths = jnp.full((tokens.shape[0],), seq_len, jnp.int32,
                            device=self.device)
-        return logits, caches, lengths
+        return logits, caches, lengths, (counts[0] if counts else None)
 
     def decode(self, caches, lengths, tokens):
         return self._decode(self.params, caches, lengths,
@@ -171,7 +175,7 @@ class Engine:
         key = (tokens.shape, tokens.dtype)
         if key not in self._decode_shapes:
             def first_step(tokens):
-                logits, caches, lengths = self.prefill(tokens)
+                logits, caches, lengths, _ = self.prefill(tokens)
                 return caches, lengths, jnp.argmax(logits, axis=-1).astype(
                     jnp.int32)
             on_device = jax.sharding.SingleDeviceSharding(self.device)
@@ -193,7 +197,7 @@ class Engine:
                  embeds: Optional[jax.Array] = None,
                  sample_rng: Optional[jax.Array] = None) -> np.ndarray:
         """Greedy (or sampled) generation; returns (B, num_steps) tokens."""
-        logits, caches, lengths = self.prefill(tokens, embeds)
+        logits, caches, lengths, _ = self.prefill(tokens, embeds)
         out = []
         tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         for i in range(num_steps):

@@ -63,6 +63,7 @@ def test_full_config_matches_assignment(arch):
         "musicgen-medium": (48, 1536, 24, 24, 6144, 2048),
         "mixtral-8x7b": (32, 4096, 32, 8, 14336, 32000),
         "deepseek-v3-671b": (61, 7168, 128, 128, 2048, 129280),
+        "deepseek-v3-ep32": (9, 7168, 128, 128, 2048, 16160),
         "llava-next-mistral-7b": (32, 4096, 32, 8, 14336, 32000),
     }[arch]
     got = (cfg.num_layers, cfg.d_model, cfg.num_heads, cfg.num_kv_heads,

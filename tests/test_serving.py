@@ -8,18 +8,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import repro.models.moe as moe_mod
 from repro.configs import ARCH_NAMES, get_smoke_config
 from repro.models import forward, init_params
 from repro.serving.engine import BatchScheduler, Engine, EngineConfig
-
-
-@pytest.fixture(autouse=True)
-def _no_moe_drops(monkeypatch):
-    """Decode never drops tokens but the batched dense path can (capacity);
-    disable drops so the consistency comparison is exact."""
-    monkeypatch.setattr(moe_mod, "capacity",
-                        lambda t, e, k, factor=None: max(64, t * k))
 
 
 @pytest.mark.parametrize("arch", ARCH_NAMES)
@@ -37,7 +28,7 @@ def test_prefill_decode_consistency(arch, rng):
     eng = Engine(cfg, params, EngineConfig(max_len=total + 8))
 
     logits_full, _ = forward(cfg, params, toks, embeds)
-    l_pref, caches, lengths = eng.prefill(toks, embeds)
+    l_pref, caches, lengths, _ = eng.prefill(toks, embeds)
     np.testing.assert_allclose(np.asarray(l_pref),
                                np.asarray(logits_full[:, -1]),
                                atol=2e-4, rtol=2e-4)
@@ -63,7 +54,7 @@ def test_sliding_window_ring_buffer(rng):
     B, S = 1, 13            # S > window
     toks = jax.random.randint(rng, (B, S), 0, cfg.vocab_size)
     eng = Engine(cfg, params, EngineConfig(max_len=24))
-    l_pref, caches, lengths = eng.prefill(toks)
+    l_pref, caches, lengths, _ = eng.prefill(toks)
     full, _ = forward(cfg, params, toks)
     np.testing.assert_allclose(np.asarray(l_pref), np.asarray(full[:, -1]),
                                atol=2e-4, rtol=2e-4)
@@ -270,7 +261,7 @@ class _CompileLog(logging.Handler):
 def _serve_engine(eng, tokens, steps):
     """A share's engine work: one prefill, then ``steps`` greedy steps.
     Returns the prefill's logits and the (B, steps) tokens."""
-    logits, caches, lengths = eng.prefill(tokens)
+    logits, caches, lengths, _ = eng.prefill(tokens)
     first, out = logits, []
     for _ in range(steps):
         tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -413,7 +404,7 @@ def test_programs_carry_their_function_names():
     assert prefill.lower(params, tokens, None).as_text().startswith(
         "module @jit_prefill")
     eng = Engine(cfg, params, EngineConfig(max_len=32))
-    logits, caches, lengths = eng.prefill(tokens)
+    logits, caches, lengths, _ = eng.prefill(tokens)
     tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     assert decode.lower(params, caches, lengths, tok).as_text().startswith(
         "module @jit_decode_step")
