@@ -13,6 +13,7 @@ on device j mod the device count.
 from __future__ import annotations
 
 import argparse
+import concurrent.futures
 import dataclasses
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -103,6 +104,12 @@ class ShareRunner:
     previous level's weights are freed. Shares that share a device run in
     level order, so that a request swaps each level in at most once.
 
+    The shares of a dispatch that sit on different devices run at the same
+    time, each device's shares in order on a host thread of their own (one
+    worker per device the placement uses), so that every share's spans
+    still time its own device work. A dispatch on one device runs on the
+    calling thread.
+
     Each share runs one engine batch of ``min(items, SHARE_ITEMS)`` prompts
     of ``PROMPT_LEN`` tokens, then ``DECODE_STEPS`` greedy decode steps.
     Prompts are drawn from ``(rid, node)`` and weights from the level, so the
@@ -115,6 +122,10 @@ class ShareRunner:
         self._node_idx = {name: j for j, name in enumerate(self.placement)}
         self.ecfg = EngineConfig(max_len=CACHE_LEN)
         self.resident: Dict[jax.Device, Tuple[int, Engine]] = {}
+        devices = set(self.placement.values())
+        self._pool = (concurrent.futures.ThreadPoolExecutor(
+            len(devices), thread_name_prefix="share-runner")
+            if len(devices) > 1 else None)
 
     def _engine(self, device: jax.Device, level: int) -> Engine:
         """The engine for ``level`` on ``device``. Swapping it in is
@@ -139,14 +150,28 @@ class ShareRunner:
         return rng.integers(0, vocab, (n, PROMPT_LEN), dtype=np.int32)
 
     def run(self, d: Dispatch) -> List[ShareResult]:
+        """Serve every share with items; results in (device, level) order.
+        An error in any share is raised here once every device is done."""
+        rid = d.request.rid
         shares = [a for a in d.assignments if a.items > 0]
         shares.sort(key=lambda a: (self.placement[a.node].id, a.apx_level))
+        groups: Dict[jax.Device, list] = {}
+        for a in shares:
+            groups.setdefault(self.placement[a.node], []).append(a)
         with spans.record("runner.run"):
-            return [self._serve(d.request.rid, a) for a in shares]
+            if len(groups) <= 1:
+                return [self._serve(rid, a) for a in shares]
+            futures = [self._pool.submit(self._serve_all, rid, group)
+                       for group in groups.values()]
+            concurrent.futures.wait(futures)
+            return [r for f in futures for r in f.result()]
+
+    def _serve_all(self, rid: int, shares) -> List[ShareResult]:
+        return [self._serve(rid, a) for a in shares]
 
     def _serve(self, rid: int, a) -> ShareResult:
         device = self.placement[a.node]
-        compiled = spans.compiles()
+        compiled = spans.thread_compiles()
         with spans.collect("runner.share") as got:
             eng = self._engine(device, a.apx_level)
             n = min(a.items, SHARE_ITEMS)
@@ -181,11 +206,14 @@ class ShareRunner:
                        "decode": spans.seconds(got, "engine.aot_decode")},
             prefill_s=spans.seconds(got, "engine.prefill"),
             decode_step_s=spans.seconds(got, "engine.decode") / DECODE_STEPS,
-            rid=rid, spans=got, compiles=spans.compiles() - compiled,
+            rid=rid, spans=got, compiles=spans.thread_compiles() - compiled,
             expert_rows=int(rows), expert_slots=int(slots))
 
     def close(self):
-        """Free every resident engine's weights."""
+        """Stop the device threads and free every resident engine's
+        weights."""
+        if self._pool is not None:
+            self._pool.shutdown()
         for _, eng in self.resident.values():
             eng.release()
         self.resident.clear()

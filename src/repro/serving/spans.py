@@ -9,7 +9,10 @@ index of the span that encloses it. There is no switch: the profiler being
 active is the only "on".
 
 ``compiles()`` counts the programs this process has compiled or loaded from
-the persistent compilation cache since its first call.
+the persistent compilation cache since its first call; ``thread_compiles()``
+counts those the calling thread compiled or loaded (JAX compiles on the
+thread that asks for the program), so that work running at once on other
+threads is not counted in it.
 """
 from __future__ import annotations
 
@@ -88,17 +91,31 @@ _compiled: Optional[int] = None     # None until the listener is installed
 def _on_duration_event(event: str, duration: float, **kwargs) -> None:
     global _compiled
     if event == COMPILE_EVENT:
+        _local.compiled = getattr(_local, "compiled", 0) + 1
         with _lock:
             _compiled += 1
+
+
+def _listen() -> None:
+    """Install the listener on the first call (with ``_lock`` held)."""
+    global _compiled
+    if _compiled is None:
+        _compiled = 0
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_duration_event)
 
 
 def compiles() -> int:
     """Programs compiled or loaded by this process since the first call:
     read it before and after a stretch of work and take the difference."""
-    global _compiled
     with _lock:
-        if _compiled is None:
-            _compiled = 0
-            jax.monitoring.register_event_duration_secs_listener(
-                _on_duration_event)
+        _listen()
         return _compiled
+
+
+def thread_compiles() -> int:
+    """Programs compiled or loaded by the calling thread since the first
+    call of either counter: as ``compiles()``, for one thread's work."""
+    with _lock:
+        _listen()
+    return getattr(_local, "compiled", 0)

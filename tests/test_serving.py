@@ -421,3 +421,245 @@ def test_a_swap_releases_outside_build(served_twice):
     assert rel.parent == build.parent == 0
     assert rel.end_ns <= build.start_ns
     assert other.build_s == build.seconds
+
+
+# --- shares on several devices at once (ShareRunner.run) ---
+
+class _Dev(tuple):
+    """A stand-in device: only its ``id`` is read where no engine is
+    built."""
+
+    @property
+    def id(self):
+        return self[0]
+
+
+def _dispatch(shares, rid=11):
+    """A dispatch of ``(node, items, level)`` shares."""
+    from repro.core.requests import Assignment, Dispatch, InferenceRequest
+    req = InferenceRequest(rid=rid, num_items=sum(s[1] for s in shares),
+                           perf_req=1.0, acc_req=0.0)
+    return Dispatch(request=req, policy="test", assignments=tuple(
+        Assignment(node=n, items=i, apx_level=lv, perf_alloc=0.0)
+        for n, i, lv in shares))
+
+
+def _stand_in_runner(monkeypatch, device_of, serve):
+    """A runner over stand-in devices (``device_of``: node -> device id)
+    whose ``_serve`` is ``serve(runner, rid, assignment)``."""
+    from repro.launch.serve import ShareRunner
+    monkeypatch.setattr(ShareRunner, "_serve", serve)
+    return ShareRunner(get_smoke_config("phi4-mini-3.8b"),
+                       {n: _Dev((j,)) for n, j in device_of.items()})
+
+
+def test_shares_on_four_devices_run_at_once(monkeypatch):
+    """Each share waits until all four are in flight: served one after
+    another, the barrier breaks."""
+    import threading
+    barrier = threading.Barrier(4, timeout=20)
+    threads = {}
+
+    def serve(runner, rid, a):
+        threads[a.node] = threading.get_ident()
+        barrier.wait()
+        return a.node
+    runner = _stand_in_runner(monkeypatch, {f"n{j}": j for j in range(4)},
+                              serve)
+    try:
+        got = runner.run(_dispatch([(f"n{j}", 8, 0) for j in range(4)]))
+    finally:
+        runner.close()
+    assert got == ["n0", "n1", "n2", "n3"]
+    assert len(set(threads.values())) == 4
+    assert threading.get_ident() not in threads.values()
+
+
+def test_shares_on_one_device_run_inline_in_level_order(monkeypatch):
+    import threading
+    calls = []
+
+    def serve(runner, rid, a):
+        calls.append((a.node, a.apx_level, threading.get_ident()))
+        return a.node
+    runner = _stand_in_runner(monkeypatch, {f"n{j}": 3 for j in range(4)},
+                              serve)
+    assert runner._pool is None
+    got = runner.run(_dispatch([("n0", 8, 2), ("n1", 8, 0), ("n2", 0, 0),
+                                ("n3", 8, 1)]))
+    assert got == ["n1", "n3", "n0"]            # by level; n2 has no items
+    assert [c[:2] for c in calls] == [("n1", 0), ("n3", 1), ("n0", 2)]
+    assert {c[2] for c in calls} == {threading.get_ident()}
+
+
+def test_results_come_back_in_device_then_level_order(monkeypatch):
+    """Shares on three devices, two per device at different levels: each
+    device's shares run in level order on one thread, and the results come
+    back in (device, level) order, as when every share ran in turn."""
+    import threading
+    order = {}
+
+    def serve(runner, rid, a):
+        order.setdefault(runner.placement[a.node].id, []).append(
+            (a.apx_level, threading.get_ident()))
+        return (runner.placement[a.node].id, a.apx_level, a.node, rid)
+    device_of = {"a": 2, "b": 0, "c": 1, "d": 2, "e": 0, "f": 1}
+    runner = _stand_in_runner(monkeypatch, device_of, serve)
+    shares = [("a", 8, 3), ("b", 8, 5), ("c", 4, 1), ("d", 8, 0),
+              ("e", 2, 1), ("f", 8, 4)]
+    try:
+        got = runner.run(_dispatch(shares, rid=5))
+    finally:
+        runner.close()
+    want = sorted((device_of[n], lv, n, 5) for n, _, lv in shares)
+    assert got == want
+    for dev, seen in order.items():
+        assert [lv for lv, _ in seen] == sorted(lv for lv, _ in seen)
+        assert len({t for _, t in seen}) == 1   # one thread per device
+
+
+def test_a_failing_share_raises_from_run_and_the_runner_goes_on(
+        monkeypatch):
+    """An error in one device's share comes out of ``run`` after the other
+    devices' shares have finished, and the runner serves the next
+    dispatch."""
+    served, fail = [], {"n2"}
+
+    def serve(runner, rid, a):
+        if a.node in fail:
+            raise ValueError(f"share {a.node} failed")
+        served.append((rid, a.node))
+        return a.node
+    runner = _stand_in_runner(monkeypatch, {f"n{j}": j for j in range(4)},
+                              serve)
+    try:
+        with pytest.raises(ValueError, match="share n2 failed"):
+            runner.run(_dispatch([(f"n{j}", 8, 0) for j in range(4)], rid=1))
+        assert sorted(served) == [(1, "n0"), (1, "n1"), (1, "n3")]
+        fail.clear()
+        got = runner.run(_dispatch([(f"n{j}", 8, 0) for j in range(4)],
+                                   rid=2))
+    finally:
+        runner.close()
+    assert got == ["n0", "n1", "n2", "n3"]
+
+
+_SPREAD_VS_ONE = r"""
+import jax, numpy as np
+from repro.configs import get_smoke_config
+from repro.launch.serve import ShareRunner, place_nodes
+from repro.core.requests import Assignment, Dispatch, InferenceRequest
+
+devices = jax.devices()
+assert len(devices) == 4, devices
+nodes = ["n0", "n1", "n2", "n3"]
+d = Dispatch(request=InferenceRequest(rid=9, num_items=16, perf_req=1.0,
+                                      acc_req=0.0),
+             policy="test", assignments=tuple(
+    Assignment(node=n, items=4, apx_level=lv, perf_alloc=0.0)
+    for n, lv in zip(nodes, (0, 0, 1, 1))))
+cfg = get_smoke_config("phi4-mini-3.8b")
+out = []
+for devs in (devices, devices[:1]):
+    runner = ShareRunner(cfg, place_nodes(nodes, devs))
+    out.append(runner.run(d))
+    runner.close()
+spread, alone = out
+assert len({s.device for s in spread}) == 4, [s.device for s in spread]
+assert len({s.device for s in alone}) == 1
+assert [(s.node, s.level, s.served) for s in spread] == \
+    [(s.node, s.level, s.served) for s in alone]
+for s, r in zip(spread, alone):
+    np.testing.assert_array_equal(s.tokens, r.tokens)
+    np.testing.assert_array_equal(s.logits, r.logits)
+print("SAME", [(s.node, s.level, s.device) for s in spread])
+"""
+
+
+def test_shares_on_four_devices_equal_shares_on_one():
+    """One dispatch served with its four nodes on four CPU devices, then
+    all on one: the same shares in the same order, tokens and logits
+    equal bit for bit (a process of its own: the device count is fixed at
+    JAX's start)."""
+    import os
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "XLA_FLAGS": "--xla_force_host_platform_device_count=4",
+           "PYTHONPATH": os.path.join(root, "src")}
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    proc = subprocess.run([sys.executable, "-c", _SPREAD_VS_ONE], env=env,
+                          capture_output=True, text=True, timeout=240,
+                          cwd=root)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert "SAME" in proc.stdout
+
+
+def test_a_compile_on_another_thread_is_not_the_shares():
+    """A program compiled on another thread while a share is in flight
+    counts in ``compiles()`` but not in the share's ``compiles``."""
+    import threading
+    from repro.launch.serve import ShareRunner, place_nodes
+    from repro.serving import spans
+    runner = ShareRunner(get_smoke_config("phi4-mini-3.8b"),
+                         place_nodes(["n0"], jax.devices()[:1]))
+    d = _one_share_dispatch("n0", items=2, rid=4)
+    runner.run(d)                               # compiles the share's shape
+    prompts = runner._prompts
+
+    x, elsewhere = jnp.ones((3, 11)), []
+
+    def compile_elsewhere():
+        n = spans.thread_compiles()
+        jax.jit(lambda x: jnp.sin(x) * 5.0 - 2.0).lower(x).compile()
+        elsewhere.append(spans.thread_compiles() - n)
+
+    def prompts_while_another_thread_compiles(*args):
+        t = threading.Thread(target=compile_elsewhere)
+        t.start()
+        t.join(timeout=60)
+        assert not t.is_alive()
+        return prompts(*args)
+    runner._prompts = prompts_while_another_thread_compiles
+    before = spans.compiles()
+    again, = runner.run(d)
+    process = spans.compiles() - before
+    runner.close()
+    assert elsewhere == [1]
+    assert process == 1
+    assert again.compiles == 0
+
+
+def test_compile_counters_add_up_across_threads():
+    """Threads compiling at once, with a short switch interval: each
+    thread's count is its own programs, and the process count is their
+    sum (a lost update would break either)."""
+    import sys
+    import threading
+    from repro.serving import spans
+    n_threads, per_thread = 12, 3
+    counted = [None] * n_threads
+
+    def work(i):
+        n = spans.thread_compiles()
+        for j in range(per_thread):
+            scale = float(1000 * i + j + 1)
+            jax.jit(lambda x: jnp.cos(x) * scale).lower(
+                jax.ShapeDtypeStruct((i + 2, j + 3), jnp.float32)).compile()
+        counted[i] = spans.thread_compiles() - n
+    interval = sys.getswitchinterval()
+    before = spans.compiles()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=work, args=(i,))
+                   for i in range(n_threads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert counted == [per_thread] * n_threads
+    assert spans.compiles() - before == n_threads * per_thread
