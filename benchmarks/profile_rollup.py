@@ -16,8 +16,7 @@ sim layer *calls* the sched layer on every arrival), so fractions of
 cumtime would sum past 1. Self-time fractions partition total CPU
 exactly.
 
-Shared by ``run_sim.py --profile`` and ``bench_sched.py --hotpath`` so
-both drivers report the same rollup shape.
+``run_sim.py --profile`` prints this rollup after its sweep.
 """
 from __future__ import annotations
 

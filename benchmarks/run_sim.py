@@ -44,10 +44,7 @@ or with an explicit path) also writes a compact ``BENCH_3.json``
 default at the repo root; the committed copy is the perf-trajectory
 anchor future PRs diff against, so only the nightly's full sweep shape
 (``--scenario all --horizon 15``) should refresh it — hence the
-explicit opt-in rather than piggybacking on every ``--json``. The
-control-plane microbenchmark trajectory (plans/sec, events/sec vs the
-retained pre-PR implementation) lives next door in ``bench_sched.py``
--> ``BENCH_4.json``.
+explicit opt-in rather than piggybacking on every ``--json``.
 
 Continuous batching: ``--max-batch`` sweeps engine-batch caps (1 =
 batching off, the pre-batching execution model — its CSV stays
@@ -284,7 +281,7 @@ def run_one(scenario_name: str, policy: str, control: str, *, seed: int,
          "ready_s": a.ready_s, "reason": a.reason}
         for a in report.scaling]
     # control-plane wall-clock: the whole cell (table build + trace +
-    # sim) and the event loop alone — the trajectory BENCH_4.json anchors
+    # sim) and the event loop alone
     row["wall_clock_s"] = time.perf_counter() - t_wall
     row["sim_wall_s"] = report.wall_s
     row["sim_events"] = report.n_events
@@ -601,13 +598,6 @@ def main(argv=None) -> int:
                     print(",".join(out))
                     if args.tenants and "tenants" in row:
                         _print_tenants(row)
-    # plan-cache effectiveness across the sweep: the counters ride on
-    # every row's summary, so the aggregate hit rate is free to report
-    hits = sum(row.get("plan_cache_hits", 0.0) for row in rows)
-    misses = sum(row.get("plan_cache_misses", 0.0) for row in rows)
-    if hits + misses > 0:
-        print(f"plan cache: {hits:.0f}/{hits + misses:.0f} plans reused "
-              f"(hit rate {hits / (hits + misses):.3f})", file=sys.stderr)
     if profiler is not None:
         import pstats
 
@@ -782,9 +772,7 @@ def write_batch_bench(rows, args, batches, path: str = BENCH_BATCH):
     max_batch, plus an ``ab`` section with the batching-on/off goodput
     ratio per cell (on = the largest swept cap, off = max_batch 1). The
     committed copy is refreshed by the nightly ``--max-batch 1,32
-    --seq-len 8`` overload sweep; ``bench_sched.py --check`` gates the
-    batching cells (goodput ratio + plan-error bound) via the
-    ``batching`` section it measures into BENCH_4."""
+    --seq-len 8`` overload sweep."""
     cells = {
         (f"{r['scenario']}/{r['policy']}/{r['control']}"
          f"/b{r['max_batch']}"): {

@@ -122,8 +122,7 @@ class AdmissionController:
                  degrade: bool = True, feasibility_margin: float = 0.02,
                  tenant_rate: Optional[float] = None,
                  tenant_burst: float = 8.0,
-                 tenant_rates: Optional[Dict[str, float]] = None,
-                 plan_cache: bool = True):
+                 tenant_rates: Optional[Dict[str, float]] = None):
         # ``table`` is accepted for constructor compatibility only: since
         # the plan-aware rewrite the gate reads capacity/accuracies/
         # backlogs exclusively from the ClusterState snapshot, never from
@@ -143,35 +142,11 @@ class AdmissionController:
         self.tenant_rates: Dict[str, float] = dict(tenant_rates or {})
         self.tenant_buckets: Dict[str, TokenBucket] = {}
         self.counts: Dict[str, int] = {ADMIT: 0, DEGRADE: 0, REJECT: 0}
-        # plan-reuse admission: every ``decide`` routes its planning
-        # through the policy's selection/assembly split, so recurring
-        # (plan_key, level-vector, size) lines replay their cached
-        # assembly bit-identically instead of rebuilding it. False
-        # disables the reuse cache on the planner (the pre-reuse cold
-        # path, retained for the hotpath benchmark's reference stack).
-        self.plan_cache = plan_cache
 
     def _planner(self) -> Policy:
         if self.policy is None:
             self.policy = resolve_policy("proportional")
-        if not self.plan_cache:
-            reuse = getattr(self.policy, "_reuse", None)
-            if reuse is not None:
-                reuse.enabled = False
         return self.policy
-
-    # hit/miss counters of the planner's reuse cache (0/0 before the
-    # first plan or for a reuse-less policy); surfaced via
-    # ``SimReport.summary`` so every sweep artifact carries the rate
-    @property
-    def plan_cache_hits(self) -> int:
-        reuse = getattr(self.policy, "_reuse", None)
-        return reuse.hits if reuse is not None else 0
-
-    @property
-    def plan_cache_misses(self) -> int:
-        reuse = getattr(self.policy, "_reuse", None)
-        return reuse.misses if reuse is not None else 0
 
     def _tenant_bucket(self, tenant: str) -> Optional[TokenBucket]:
         """Lazily build the tenant's bucket; None when that tenant is

@@ -93,7 +93,6 @@ class GatewayNode:
     def __init__(self, table: ProfilingTable, backend: SimBackend,
                  policy: Union[str, Policy] = "proportional", *,
                  straggler_ewma: float = 0.5,
-                 snapshot_caching: bool = True,
                  max_batch: int = 1):
         self.table = table
         self.backend = backend
@@ -104,10 +103,8 @@ class GatewayNode:
         assert max_batch >= 1, "max_batch must be >= 1"
         self.max_batch = max_batch
         # copy-on-write snapshots: one frozen profiling view shared across
-        # snapshots until the table's version says it mutated. False
-        # forces a full copy per snapshot (the pre-PR baseline the bench
-        # measures against; it also leaves Plan memo keys unset)
-        self._snap_cache = SnapshotCache() if snapshot_caching else None
+        # snapshots until the table's version says it mutated
+        self._snap_cache = SnapshotCache()
         self.policy_obj: Policy = resolve_policy(policy)
         self.policy: str = self.policy_obj.name   # registry name (reports)
         self.state = GNState.PROFILE
@@ -181,15 +178,10 @@ class GatewayNode:
         thing a policy (or the admission gate) ever reads. Snapshots are
         copy-on-write: the heavy arrays are shared until a table mutation
         bumps ``ProfilingTable.version``."""
-        if self._snap_cache is not None:
-            return self._snap_cache.snapshot(self.table, now=now,
-                                             backlogs=backlogs,
-                                             standby=tuple(standby),
-                                             max_batch=self.max_batch)
-        return ClusterState.from_table(self.table, now=now,
-                                       backlogs=backlogs,
-                                       standby=tuple(standby),
-                                       max_batch=self.max_batch)
+        return self._snap_cache.snapshot(self.table, now=now,
+                                         backlogs=backlogs,
+                                         standby=tuple(standby),
+                                         max_batch=self.max_batch)
 
     def plan(self, request: InferenceRequest, *, now: float = 0.0,
              backlogs: Optional[Mapping[str, float]] = None,

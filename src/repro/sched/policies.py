@@ -23,10 +23,12 @@ ProfilingTable through a side channel.
 
 Performance: this module is the per-request hot path (DistrEdge's point
 that the distribution step must be cheap enough to run per request), so
-the planners are vectorized and memoized against the snapshot's
-``plan_key`` — see the module docstring of :mod:`repro.sched.reference`
-(the retained pre-optimization implementation these are proven
-bit-identical to) and repro/sched/README.md §Performance.
+the planners are vectorized and their level selections (the DP, the
+oracle's enumeration) memoized against the snapshot's ``plan_key``; plan
+assembly (:func:`_mk_plan`) runs on every call. See the module docstring
+of :mod:`repro.sched.reference` (the retained pre-optimization
+implementation these are proven bit-identical to) and
+repro/sched/README.md §Performance.
 """
 from __future__ import annotations
 
@@ -141,176 +143,18 @@ def _mk_plan(state: ClusterState, request: InferenceRequest,
         meta=types.MappingProxyType(dict(meta or {})))
 
 
-# ---- plan-reuse (selection/assembly split) ---------------------------
-def _assembly_key(state: ClusterState, levels: np.ndarray,
-                  num_items: int) -> Optional[tuple]:
-    """Reuse key for a (levels, num_items) assembly on this snapshot:
-    the plan_key pins the profiling view / serving mask / batch cap, the
-    level bytes pin the selection outcome. Batched assemblies also read
-    the available nodes' backlogs (the quantized split's greedy tail
-    placement ranks nodes by backlog + grown service), so the key
-    carries exactly those reads — a backlog move on any available node
-    must miss, an unavailable node's cannot matter."""
-    pk = state.plan_key
-    if pk is None:
-        return None
-    if state.batched:
-        backlog = state.backlog_s
-        names = state.names
-        reads = tuple(backlog.get(names[c], 0.0)
-                      for c in state.avail_idx.tolist())
-        return (pk, levels.tobytes(), num_items, reads)
-    return (pk, levels.tobytes(), num_items)
-
-
-@dataclasses.dataclass
-class PlanSelection:
-    """Outcome of a policy's *selection* stage: which per-node levels
-    (plus optional shares/meta) the policy chose, and the reuse key that
-    makes the subsequent assembly replayable.
-
-    ``key`` is ``None`` when the selection is uncacheable (no
-    ``plan_key`` on the snapshot, or an oracle fallback); otherwise it
-    is :func:`_assembly_key` — everything the assembly in
-    :func:`_mk_plan` reads besides the now / perf_req / finish-time
-    backlogs, which the replay recomputes exactly. ``plan`` is set
-    when the selection stage already had to build the full Plan (EDF's
-    feasibility walk probes assemblies; the oracle fallback wraps the
-    heuristic's plan) — assembly then has nothing left to do."""
-    key: Optional[tuple]
-    idx: Optional[np.ndarray] = None
-    levels: Optional[np.ndarray] = None
-    shares: Optional[np.ndarray] = None
-    meta: Optional[Mapping[str, object]] = None
-    plan: Optional[Plan] = None
-
-
-class _ReuseState:
-    """Mutable plan-reuse state carried by each (frozen) policy
-    instance: the assembly cache plus hit/miss counters. A plain
-    attribute bag (not a dataclass field default) so the reference
-    bench stack can flip ``enabled`` off without touching the frozen
-    policy object itself."""
-
-    __slots__ = ("enabled", "hits", "misses", "entries")
-
-    MAX_ENTRIES = 4096          # clear-all eviction, like the DP memo
-
-    def __init__(self):
-        self.enabled = True
-        self.hits = 0
-        self.misses = 0
-        self.entries: Dict[tuple, "_PlanEntry"] = {}
-
-
-class _PlanEntry:
-    """The request-independent residue of one assembled Plan.
-
-    Everything here is a pure function of the reuse key — (plan_key,
-    levels, num_items) pins the profiling view, the serving mask, the
-    batch cap, and the workload split, so assignments / service times /
-    alloc_perf / predicted_acc cannot differ between the cached build
-    and a replay. The per-call inputs (snapshot time, backlogs,
-    perf_req) are re-applied in :meth:`replay` with exactly the
-    arithmetic :func:`_mk_plan` uses, so a replayed Plan is
-    bit-identical to a cold assembly."""
-
-    __slots__ = ("policy", "assignments", "service", "exec_makespan_s",
-                 "alloc_perf", "predicted_acc", "meta")
-
-    def __init__(self, plan: Plan):
-        self.policy = plan.policy
-        self.assignments = plan.dispatch.assignments
-        self.service = plan.node_service_s      # immutable proxy, shared
-        self.exec_makespan_s = plan.exec_makespan_s
-        self.alloc_perf = plan.alloc_perf
-        self.predicted_acc = plan.predicted_acc
-        self.meta = plan.meta                   # immutable proxy, shared
-
-    def replay(self, state: ClusterState,
-               request: InferenceRequest) -> Plan:
-        now = state.now_s
-        backlog = state.backlog_s
-        finish: dict = {}
-        # same insertion order as the cold assembly: ``service`` kept
-        # the node order of the avail_idx walk that built it
-        for node, t in self.service.items():
-            finish[node] = now + backlog.get(node, 0.0) + t
-        finish_s = max(finish.values(), default=now)
-        return Plan(
-            dispatch=Dispatch(request=request,
-                              assignments=self.assignments,
-                              policy=self.policy),
-            policy=self.policy, created_s=now,
-            node_service_s=self.service,
-            node_finish_s=types.MappingProxyType(finish),
-            exec_makespan_s=self.exec_makespan_s,
-            makespan_s=finish_s - now, finish_s=finish_s,
-            alloc_perf=self.alloc_perf,
-            predicted_acc=self.predicted_acc,
-            feasible=bool(self.alloc_perf
-                          >= request.perf_req * (1 - 1e-9)),
-            meta=self.meta)
-
-
-def _plan_with_reuse(policy, state: ClusterState,
-                     request: InferenceRequest) -> Plan:
-    """``plan()`` = ``select()`` + cached assembly.
-
-    Selection (the DP / threshold scan / enumeration residue) runs on
-    every call — it is what decides the levels and it is cheap and
-    memoized on its own terms. Assembly (the O(nodes) split + Assignment
-    construction in :func:`_mk_plan`) is reused across requests whose
-    selection landed on the same (plan_key, levels, num_items) line:
-    the replay re-applies the per-call backlogs / snapshot time /
-    perf_req and returns a Plan bit-identical to a cold build (pinned by
-    the golden digests and tests/test_eventloop_property.py)."""
-    reuse = policy._reuse
-    sel = policy.select(state, request)
-    key = sel.key if reuse.enabled else None
-    if key is None:
-        reuse.misses += 1
-        if sel.plan is not None:
-            return sel.plan
-        return _mk_plan(state, request, sel.idx, sel.levels, policy.name,
-                        sel.shares, sel.meta)
-    entry = reuse.entries.get(key)
-    if entry is not None:
-        reuse.hits += 1
-        if sel.plan is not None:
-            return sel.plan
-        return entry.replay(state, request)
-    reuse.misses += 1
-    plan = sel.plan
-    if plan is None:
-        plan = _mk_plan(state, request, sel.idx, sel.levels, policy.name,
-                        sel.shares, sel.meta)
-    if len(reuse.entries) >= _ReuseState.MAX_ENTRIES:
-        reuse.entries.clear()
-    reuse.entries[key] = _PlanEntry(plan)
-    return plan
-
-
 # ----------------------------------------------------------------------
 @register_policy("uniform")
 @dataclasses.dataclass(frozen=True)
 class Uniform:
     """MoDNN-style equal split at full accuracy."""
     name: str = "uniform"
-    _reuse: _ReuseState = dataclasses.field(default_factory=_ReuseState,
-                                            repr=False, compare=False)
 
-    def select(self, state: ClusterState,
-               request: InferenceRequest) -> PlanSelection:
+    def plan(self, state: ClusterState, request: InferenceRequest) -> Plan:
         idx = _avail(state)
         levels = np.zeros(len(idx), dtype=int)
         shares = np.ones(len(idx)) / len(idx)
-        key = _assembly_key(state, levels, request.num_items)
-        return PlanSelection(key=key, idx=idx, levels=levels,
-                             shares=shares)
-
-    def plan(self, state: ClusterState, request: InferenceRequest) -> Plan:
-        return _plan_with_reuse(self, state, request)
+        return _mk_plan(state, request, idx, levels, self.name, shares)
 
 
 @register_policy("uniform_apx")
@@ -320,11 +164,8 @@ class UniformApx:
     met (aggressive — the paper's accuracy-violating baseline)."""
     name: str = "uniform_apx"
     margin: float = 0.02
-    _reuse: _ReuseState = dataclasses.field(default_factory=_ReuseState,
-                                            repr=False, compare=False)
 
-    def select(self, state: ClusterState,
-               request: InferenceRequest) -> PlanSelection:
+    def plan(self, state: ClusterState, request: InferenceRequest) -> Plan:
         idx = _avail(state)
         n = len(idx)
         per_node = (request.perf_req / n) * (
@@ -335,12 +176,7 @@ class UniformApx:
         levels = np.where(hit.any(axis=0), hit.argmax(axis=0),
                           state.num_levels - 1)
         shares = np.ones(n) / n
-        key = _assembly_key(state, levels, request.num_items)
-        return PlanSelection(key=key, idx=idx, levels=levels,
-                             shares=shares)
-
-    def plan(self, state: ClusterState, request: InferenceRequest) -> Plan:
-        return _plan_with_reuse(self, state, request)
+        return _mk_plan(state, request, idx, levels, self.name, shares)
 
 
 @register_policy("asymmetric")
@@ -348,25 +184,30 @@ class UniformApx:
 class Asymmetric:
     """Legion-style capability-proportional split, no approximation."""
     name: str = "asymmetric"
-    _reuse: _ReuseState = dataclasses.field(default_factory=_ReuseState,
-                                            repr=False, compare=False)
 
-    def select(self, state: ClusterState,
-               request: InferenceRequest) -> PlanSelection:
+    def plan(self, state: ClusterState, request: InferenceRequest) -> Plan:
         idx = _avail(state)
         caps = (state.eff_perf if state.batched
                 else state.perf)[0, idx]
         shares = caps / caps.sum()
         levels = np.zeros(len(idx), dtype=int)
-        key = _assembly_key(state, levels, request.num_items)
-        return PlanSelection(key=key, idx=idx, levels=levels,
-                             shares=shares)
-
-    def plan(self, state: ClusterState, request: InferenceRequest) -> Plan:
-        return _plan_with_reuse(self, state, request)
+        return _mk_plan(state, request, idx, levels, self.name, shares)
 
 
 # ----------------------------------------------------------------------
+@dataclasses.dataclass(frozen=True)
+class PlanSelection:
+    """``Proportional.select``'s outcome: the available node columns, the
+    level each serves at, and the DP memo line it came from (``key``;
+    None when the snapshot has no ``plan_key``). Selection stays a method
+    of its own so a test can substitute it:
+    ``benchmarks/chip/tests/test_compare.py`` plans one level deeper than
+    the DP chose and checks the accuracy guarantee catches it."""
+    idx: np.ndarray
+    levels: np.ndarray
+    key: Optional[tuple] = None
+
+
 @register_policy("proportional")
 @dataclasses.dataclass(frozen=True)
 class Proportional:
@@ -386,21 +227,24 @@ class Proportional:
     The DP result is memoized on ``(plan_key, target)``: the level
     vector depends on the request only through the margin-adjusted
     throughput target, so steady-state traffic (recurring request
-    classes against an unchanged cluster) plans from cache and pays only
-    the O(n) plan assembly. Snapshots without a ``plan_key`` (hand-built
-    ``from_table`` states) always plan cold.
+    classes against an unchanged cluster) skips the DP and pays only the
+    O(n) plan assembly. Snapshots without a ``plan_key`` (hand-built
+    ``from_table`` states) always run the DP.
     """
     name: str = "proportional"
     margin: float = 0.02
     _dp_cache: Dict = dataclasses.field(default_factory=dict,
                                         repr=False, compare=False)
-    _reuse: _ReuseState = dataclasses.field(default_factory=_ReuseState,
-                                            repr=False, compare=False)
 
     _DP_CACHE_MAX = 4096
 
+    def plan(self, state: ClusterState, request: InferenceRequest) -> Plan:
+        sel = self.select(state, request)
+        return _mk_plan(state, request, sel.idx, sel.levels, self.name)
+
     def select(self, state: ClusterState,
                request: InferenceRequest) -> PlanSelection:
+        """Lines 3-14: the available nodes and their levels."""
         idx = _avail(state)
         n = len(idx)
         # headroom over perf_req: integer workload splits quantise the
@@ -414,9 +258,7 @@ class Proportional:
             key = (pk, target)
             levels = self._dp_cache.get(key)
             if levels is not None:
-                return PlanSelection(
-                    key=_assembly_key(state, levels, request.num_items),
-                    idx=idx, levels=levels)
+                return PlanSelection(idx=idx, levels=levels, key=key)
 
         pruned = state.available_eff_perf              # lines 3-5
         perf_vector = pruned.sum(axis=1)               # lines 6-7
@@ -432,11 +274,8 @@ class Proportional:
                 self._dp_cache.clear()
             levels.flags.writeable = False
             self._dp_cache[key] = levels
-        reuse_key = _assembly_key(state, levels, request.num_items)
-        return PlanSelection(key=reuse_key, idx=idx, levels=levels)
+        return PlanSelection(idx=idx, levels=levels, key=key)
 
-    def plan(self, state: ClusterState, request: InferenceRequest) -> Plan:
-        return _plan_with_reuse(self, state, request)
 
 
 def _subset_sum_dp(pruned: np.ndarray, perf_b_req: np.ndarray,
@@ -569,13 +408,10 @@ class ExactOracle:
     # reuse its DP memo instead of re-solving per request
     _fallback: Proportional = dataclasses.field(
         default_factory=Proportional, repr=False, compare=False)
-    _reuse: _ReuseState = dataclasses.field(default_factory=_ReuseState,
-                                            repr=False, compare=False)
 
     _ENUM_CACHE_MAX = 4          # entries are MB-scale tensors
 
-    def select(self, state: ClusterState,
-               request: InferenceRequest) -> PlanSelection:
+    def plan(self, state: ClusterState, request: InferenceRequest) -> Plan:
         idx = _avail(state)
         pruned = state.available_eff_perf
         acc = state.accuracies
@@ -589,11 +425,8 @@ class ExactOracle:
             for c in cands:
                 budget //= len(c)
             if budget == 0:             # prod(len(c)) > max_enum_combos
-                # fallback plans are uncacheable at this layer (key=None)
-                # but the shared fallback planner brings its own reuse
-                # cache, so large-fleet heuristic plans still replay
                 fb = self._fallback.plan(state, request)
-                return PlanSelection(key=None, plan=dataclasses.replace(
+                return dataclasses.replace(
                     fb,
                     dispatch=Dispatch(request=fb.dispatch.request,
                                       assignments=fb.dispatch.assignments,
@@ -604,7 +437,7 @@ class ExactOracle:
                          "reason": f"n={n} > max_enum_nodes="
                                    f"{self.max_enum_nodes} and pruned grid"
                                    f" > max_enum_combos="
-                                   f"{self.max_enum_combos}"})))
+                                   f"{self.max_enum_combos}"}))
             meta = {"enum": "dominated_pruned", "n": n}
 
         combos, total_q, order, argmax_total = self._enumerate(
@@ -616,11 +449,7 @@ class ExactOracle:
         pos = _first_at_least(total_q, request.perf_req * 1.02)
         best = int(order[pos]) if pos >= 0 else argmax_total
         levels = combos[best].astype(int)
-        key = _assembly_key(state, levels, request.num_items)
-        return PlanSelection(key=key, idx=idx, levels=levels, meta=meta)
-
-    def plan(self, state: ClusterState, request: InferenceRequest) -> Plan:
-        return _plan_with_reuse(self, state, request)
+        return _mk_plan(state, request, idx, levels, self.name, meta=meta)
 
     def _enumerate(self, state: ClusterState, pruned: np.ndarray,
                    acc: np.ndarray, cands) -> Tuple[np.ndarray, ...]:
@@ -676,28 +505,15 @@ class AccuracyEDF:
     backlog or batching changes what the deadline can afford.
     """
     name: str = "accuracy_edf"
-    _reuse: _ReuseState = dataclasses.field(default_factory=_ReuseState,
-                                            repr=False, compare=False)
 
-    def select(self, state: ClusterState,
-               request: InferenceRequest) -> PlanSelection:
+    def plan(self, state: ClusterState, request: InferenceRequest) -> Plan:
         idx = _avail(state)
         n = len(idx)
-        pk = state.plan_key
-        backlog = state.backlog_s
-        # the walk's feasibility probes read the backlogs of every node
-        # that carried a share in any probed assembly — those reads go
-        # into the reuse key, so a backlog change on a read node is a
-        # miss while a change on an untouched node still hits
-        reads: Dict[str, float] = {}
         plan = None
         for m in range(state.num_levels):
             levels = np.full(n, m, dtype=int)
             plan = _mk_plan(state, request, idx, levels, self.name,
                             meta={"edf": "met_budget", "edf_level": m})
-            for node in plan.node_service_s:
-                if node not in reads:
-                    reads[node] = backlog.get(node, 0.0)
             if plan.meets_deadline:
                 break
         else:
@@ -705,13 +521,7 @@ class AccuracyEDF:
             plan = dataclasses.replace(
                 plan, meta=types.MappingProxyType(
                     {**plan.meta, "edf": "best_effort"}))
-        key = None if pk is None else (
-            pk, request.num_items, request.latency_budget_s,
-            tuple(reads.items()))
-        return PlanSelection(key=key, plan=plan)
-
-    def plan(self, state: ClusterState, request: InferenceRequest) -> Plan:
-        return _plan_with_reuse(self, state, request)
+        return plan
 
 
 def _non_dominated_levels(pruned: np.ndarray) -> list:

@@ -13,8 +13,8 @@ against:
     levels, predicted makespan/accuracy) to these across random
     ClusterStates — the optimization only counts if the serving metrics
     are bit-stable;
-  * ``benchmarks/bench_sched.py`` times these as the pre-PR baseline the
-    plans/sec and events/sec speedups are measured against.
+  * ``tests/test_merge_property.py`` holds the oracle's fused residue
+    to the per-call enumeration here on random grids.
 
 The one deliberate semantic change shared by both implementations: the
 remainder distribution uses ``np.argsort(-perfs, kind="stable")``. The
@@ -109,8 +109,11 @@ def _mk_plan_ref(state: ClusterState, request: InferenceRequest,
         meta["assumed_batch"] = state.max_batch
     exec_makespan = max(service.values(), default=0.0)
     finish_s = max(finish.values(), default=now)
-    total_acc = sum(a.items * float(state.accuracies[a.apx_level])
-                    for a in assignments)
+    # a plain loop in assignment order, not ``sum``: since Python 3.12
+    # ``sum`` of floats is compensated, which can move the last bit
+    total_acc = 0.0
+    for a in assignments:
+        total_acc += a.items * float(state.accuracies[a.apx_level])
     return Plan(
         dispatch=dispatch, policy=policy, created_s=now,
         node_service_s=types.MappingProxyType(service),

@@ -133,9 +133,9 @@ class ClusterState:
         besides the request — the profiling view identity (table version),
         the serving mask, and the engine-batch cap the plan prices at.
         None when the snapshot has no version (hand-built), which
-        disables memoization. Cached on the instance: the planners and
-        the plan-reuse cache read it once or more per arrival, and the
-        tuple build is pure over frozen fields."""
+        disables memoization. Cached on the instance: the planners read
+        it once or more per arrival, and the tuple build is pure over
+        frozen fields."""
         if self.perf_version is None:
             return None
         key = self.__dict__.get("_plan_key")

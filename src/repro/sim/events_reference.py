@@ -3,10 +3,10 @@
 This is the event queue exactly as it shipped before the slab-backed
 rewrite in ``events.py``: every ``push`` allocates a ``SimEvent`` and
 heap-pushes a ``(time, seq, SimEvent)`` tuple. It is kept verbatim as
-the property-twin baseline — ``tests/test_eventloop_property.py`` and
-``bench_sched.py --hotpath`` drive the slab queue and this queue through
-identical op sequences and assert byte-identical event streams, the same
-retained-twin pattern as ``sched/reference.py``.
+the property-twin baseline — ``tests/test_eventloop_property.py``
+drives the slab queue and this queue through identical op sequences and
+asserts byte-identical event streams, the same retained-twin pattern as
+``sched/reference.py``.
 
 Events are ordered by (time, seq); ``seq`` is a monotonically increasing
 tie-breaker so same-timestamp events fire in push order (FIFO), which keeps

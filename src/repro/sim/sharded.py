@@ -40,8 +40,8 @@ into the *same* cell's queue, so no other merge candidate can move while
 a run is in flight and the pop order is byte-identical to the per-event
 merge. :meth:`ShardedSimulator.run_reference` retains that per-event
 merge as the bit-identity twin (the ``reference:`` pattern from
-``repro.sched.reference``); tests and ``BENCH_8.json`` pin the two
-against each other. See sim/README.md §"Root merge loop".
+``repro.sched.reference``); the property tests pin the two against
+each other. See sim/README.md §"Root merge loop".
 
 Routing happens at the arrival's own timestamp (it is routed only once
 it is the global minimum), so least-backlog decisions see the same
@@ -129,9 +129,8 @@ class ShardedSimulator:
                  reference_stack: bool = False):
         # reference_stack=True builds every cell on the retained pre-slab
         # stack: events_reference.EventQueue instead of the slab queue,
-        # and plan reuse disabled on every planner (gateway + gate). The
-        # hotpath benchmark and the property twins pin the fast stack's
-        # event stream byte-identically against this one.
+        # and the pre-fusion event dispatch. The property twins pin the
+        # fast stack's event stream byte-identically against this one.
         self.scenario = scenario
         self.horizon_s = horizon_s or (
             max((t for t, _ in arrivals), default=0.0))
@@ -193,8 +192,7 @@ class ShardedSimulator:
                               for t, r in admission_tenant_rates.items()}
                 adm = AdmissionController(ctable, rate=rate,
                                           burst=admission_burst,
-                                          tenant_rates=trates,
-                                          plan_cache=not reference_stack)
+                                          tenant_rates=trates)
             asc = None
             if autoscale:
                 # constructed even when this cell drew no standby nodes:
@@ -209,10 +207,6 @@ class ShardedSimulator:
                 # the cells=1 byte-identity guarantee is untouched.
                 fss = FairShareScheduler(fairshare_weights,
                                          quantum_items=fairshare_quantum)
-            if reference_stack:
-                reuse = getattr(gn.policy_obj, "_reuse", None)
-                if reuse is not None:
-                    reuse.enabled = False
             cell = OnlineSimulator(
                 gn, (), (), scenario=scenario, horizon_s=self.horizon_s,
                 admission=adm, autoscaler=asc, fairshare=fss,
@@ -220,8 +214,7 @@ class ShardedSimulator:
                 event_queue=queue_cls(counter))
             if reference_stack:
                 # the reference drain also dispatches through the
-                # retained pre-fusion if/elif chain, so the hotpath
-                # benchmark measures slab + fusion + reuse together
+                # retained pre-fusion if/elif chain
                 cell._handle = cell._handle_reference
             cell.on_settled = (
                 lambda rec, c=spec.cell_id: self._settled(c, rec))
@@ -396,8 +389,7 @@ class ShardedSimulator:
         ``repro.sched.reference``): a linear O(cells) scan over every
         cell queue head per event, one pop per iteration. Kept verbatim
         so the property tests can pin run-draining's event stream
-        against it and ``bench_sched.py``'s merge section (BENCH_8.json)
-        can measure the speedup on identical traffic."""
+        against it on identical traffic."""
         for cell in self.cells:
             if not cell.gn._profiled:
                 cell.gn.startup()
@@ -465,13 +457,6 @@ class ShardedSimulator:
         for cell in self.cells:
             if cell.admission is not None:
                 admission_counts.update(cell.admission.counts)
-        # per-cell planners are distinct objects (fresh policy instance
-        # per cell), so summing the per-cell deduped counts is exact
-        plan_hits = plan_misses = 0
-        for cell in self.cells:
-            h, m = cell.plan_cache_counts()
-            plan_hits += h
-            plan_misses += m
         if multi:
             log = [f"[cell{i}] {line}"
                    for i, cell in enumerate(self.cells)
@@ -489,8 +474,7 @@ class ShardedSimulator:
             log=log, scaling=scaling,
             admission_counts=dict(admission_counts),
             end_s=max(cell.clock.now for cell in self.cells),
-            n_events=n_events, wall_s=wall_s,
-            plan_cache_hits=plan_hits, plan_cache_misses=plan_misses)
+            n_events=n_events, wall_s=wall_s)
 
     # ---- introspection (benchmarks) ------------------------------------
     def plans_made(self) -> int:

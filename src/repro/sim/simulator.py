@@ -227,8 +227,8 @@ class NodeRuntime:
     def backlog_s_recompute(self, now: float,
                             predictor: Callable[[_Share], float]) -> float:
         """Pre-PR backlog read: walk the queue calling the predictor per
-        share. Retained as the baseline ``bench_sched.py`` measures the
-        incremental sensor against (``legacy_control_plane=True``)."""
+        share. Retained as the oracle the equivalence tests hold the
+        incremental sensor to (``legacy_control_plane=True``)."""
         total = self._active_remaining_s(now)
         for s in self.queue:
             total += predictor(s)
@@ -299,11 +299,6 @@ class SimReport:
     end_s: float = 0.0                # sim clock when the last event fired
     n_events: int = 0                 # events the loop processed
     wall_s: float = 0.0               # host wall-clock of run()
-    # plan-reuse cache effectiveness over the run (policy-level reuse
-    # across gate + dispatch planning); excluded from the golden digests
-    # like wall_s/n_events — telemetry, not behaviour
-    plan_cache_hits: int = 0
-    plan_cache_misses: int = 0
 
     def summary(self) -> Dict[str, float]:
         """Aggregate metrics. Latency / deadline metrics cover *admitted*
@@ -346,8 +341,6 @@ class SimReport:
         s["plan_fallbacks"] = float(sum(
             1 for r in self.records
             if r.plan is not None and "fallback" in r.plan.meta))
-        s["plan_cache_hits"] = float(self.plan_cache_hits)
-        s["plan_cache_misses"] = float(self.plan_cache_misses)
         spawns = [a for a in self.scaling if a.kind == SPAWN]
         lat = [a.ready_s - a.decided_s for a in spawns]
         s["scale_ups"] = float(len(spawns))
@@ -466,8 +459,8 @@ class OnlineSimulator:
                 "executes")
         # True routes snapshots through ClusterState.from_table (full copy
         # per event) and backlog reads through the per-share recompute —
-        # the pre-PR control plane, kept so bench_sched.py can measure
-        # the incremental path against it on identical traffic
+        # the pre-PR control plane, kept as the oracle the equivalence
+        # tests run the incremental path against on identical traffic
         self.legacy_control_plane = legacy_control_plane
         if admission is not None and admission.policy is None:
             # gate and dispatch must plan identically: the admission
@@ -547,7 +540,6 @@ class OnlineSimulator:
         n_events = self.process_run((float("inf"), -1), self.MAX_EVENTS + 1)
         if n_events > self.MAX_EVENTS:
             raise RuntimeError("simulator exceeded MAX_EVENTS")
-        hits, misses = self.plan_cache_counts()
         return SimReport(policy=self.gn.policy, scenario=self.scenario,
                          horizon_s=self.horizon_s,
                          records=[self.records[k]
@@ -559,26 +551,7 @@ class OnlineSimulator:
                                            if self.admission else {}),
                          end_s=self.clock.now,
                          n_events=n_events,
-                         plan_cache_hits=hits, plan_cache_misses=misses,
                          wall_s=time.perf_counter() - t0)  # detlint: ok[DET001] wall_s telemetry only; excluded from the golden digests
-
-    def plan_cache_counts(self) -> Tuple[int, int]:
-        """(hits, misses) of the plan-reuse caches this run planned
-        through: the GN's dispatch policy and the admission gate's
-        planner — usually the same object, deduped by identity so shared
-        counters are never double-counted."""
-        hits = misses = 0
-        seen = set()
-        planners = [self.gn.policy_obj]
-        if self.admission is not None and self.admission.policy is not None:
-            planners.append(self.admission.policy)
-        for pol in planners:
-            reuse = getattr(pol, "_reuse", None)
-            if reuse is not None and id(reuse) not in seen:  # detlint: ok[DET006] identity-dedup of shared counter objects (gate and GN usually share one planner); never an ordering key
-                seen.add(id(reuse))  # detlint: ok[DET006] same identity-dedup set
-                hits += reuse.hits
-                misses += reuse.misses
-        return hits, misses
 
     def process_next(self) -> SimEvent:
         """Pop and handle the earliest scheduled event. ``run()`` is this
@@ -691,12 +664,11 @@ class OnlineSimulator:
     def _handle_reference(self, ev: SimEvent):
         """The pre-fusion dispatch chain, retained verbatim: if/elif
         kind dispatch into the unfused helper methods (``_share_done``
-        -> ``_complete_share`` -> ``_maybe_start``). The hotpath
-        benchmark's reference stack (``ShardedSimulator(
-        reference_stack=True)``) rebinds ``_handle`` to this, and the
-        property twins pin its event stream byte-identically against
-        the fused handler table — fusion is a call-graph collapse, not
-        a semantics change."""
+        -> ``_complete_share`` -> ``_maybe_start``). The reference
+        stack (``ShardedSimulator(reference_stack=True)``) rebinds
+        ``_handle`` to this, and the property twins pin its event stream
+        byte-identically against the fused handler table — fusion is a
+        call-graph collapse, not a semantics change."""
         now = self.clock.now
         if ev.kind == "arrival":
             req: InferenceRequest = ev.payload["request"]

@@ -61,9 +61,8 @@ SECTIONS = ("records", "log", "summary")
 
 def _surfaces(report):
     """The three digested surfaces, in the exact shapes the original
-    combined digest serialized (wall-clock, event-count and plan-cache
-    counter fields excluded — they are host-speed/caching trivia, not
-    serving behaviour)."""
+    combined digest serialized (wall-clock and event-count fields
+    excluded — they are host-speed trivia, not serving behaviour)."""
     records = [
         (int(r.request.rid), repr(r.arrival_s), repr(r.dispatch_s),
          repr(r.finish_s), bool(r.rejected), r.reject_reason,
@@ -73,8 +72,7 @@ def _surfaces(report):
         for r in report.records]
     summary = sorted(
         (k, repr(v)) for k, v in report.summary().items()
-        if k not in ("wall_s", "n_events",
-                     "plan_cache_hits", "plan_cache_misses"))
+        if k not in ("wall_s", "n_events"))
     return records, list(report.log), summary
 
 

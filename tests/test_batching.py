@@ -310,19 +310,14 @@ def test_fast_vs_legacy_identity_with_batching(pool):
             policy = ("reference:proportional" if legacy
                       else "proportional")
             gn = GatewayNode(table, SimBackend(table, seed=2),
-                             policy=policy, max_batch=max_batch,
-                             snapshot_caching=not legacy)
+                             policy=policy, max_batch=max_batch)
             reps.append(OnlineSimulator(
                 gn, sc.arrivals, sc.faults, scenario=sc.name,
                 horizon_s=sc.horizon_s,
                 admission=AdmissionController(table),
                 legacy_control_plane=legacy).run())
         fast, legacy = (r.summary() for r in reps)
-        # plan-cache counters are excluded: the reference policy plans
-        # cold by design, so its hit/miss counts are trivially zero
-        mism = [k for k in fast
-                if not k.startswith("plan_cache")
-                and abs(fast[k] - legacy[k]) > 1e-9]
+        mism = [k for k in fast if abs(fast[k] - legacy[k]) > 1e-9]
         assert not mism, (max_batch, mism)
 
 

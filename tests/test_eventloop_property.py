@@ -1,12 +1,11 @@
-"""Slab event loop + plan-reuse equivalence (property-based).
+"""Slab event loop equivalence (property-based).
 
 PR 10 rebuilt the per-event hot path (slab-backed event queue, fused
-dispatch) and added plan-reuse admission; each keeps a verbatim twin
-(``events_reference.EventQueue``, ``OnlineSimulator._handle_reference``,
-cold planning via ``plan_cache=False`` / ``_reuse.enabled=False``), and
-these tests pin the optimized stack against the twins. The BENCH_9
-speedups only count because the event streams here are *identical*, not
-merely close.
+dispatch); each keeps a verbatim twin (``events_reference.EventQueue``,
+``OnlineSimulator._handle_reference``), and these tests pin the
+optimized stack against the twins: the event streams must be
+*identical*, not merely close. A last test holds warm planners to fresh
+ones, so the selection memos cannot carry state between snapshots.
 
 Like tests/test_merge_property.py, the properties run under hypothesis
 when installed and fall back to a fixed seeded sweep over the same case
@@ -29,7 +28,6 @@ from repro.core.profiling import ProfilingTable
 from repro.core.requests import InferenceRequest
 from repro.core.variants import VariantPool
 from repro.sched import get_policy
-from repro.sched.policies import _assembly_key
 from repro.sched.state import SnapshotCache
 from repro.sim import ShardedSimulator
 from repro.sim import events_reference
@@ -124,16 +122,14 @@ def test_slab_queue_grows_and_recycles():
     assert not q
 
 
-# ---- event loop: slab+fused+reuse stack vs reference stack ------------
+# ---- event loop: slab+fused stack vs reference stack -----------------
 def _table_factory(profiles):
     return ProfilingTable(POOL, profiles, seq_len=512)
 
 
 def _stream(sim, rep):
     """Everything the event loop can influence: the digest-hashed record
-    fields, the full log, the event count, and the routing decisions —
-    plus the plan-cache counters are *excluded* (the reference stack
-    plans cold by design, so they differ trivially)."""
+    fields, the full log, the event count, and the routing decisions."""
     records = []
     for rec in rep.records:
         records.append((rec.request.rid, rec.arrival_s, rec.dispatch_s,
@@ -147,9 +143,9 @@ def _stream(sim, rep):
 def _check_stack_equivalence(seed, scenario_name, max_batch, fair, gated):
     """THE tentpole property: across seeded churn/straggler/tenant
     scenarios x batching x fair-share at cells in {1, 4, 16}, the slab
-    queue + fused dispatch + plan-reuse stack produces an event stream
-    byte-identical to the retained reference stack (tuple-heap queue,
-    pre-fusion ``_handle`` chain, cold planning)."""
+    queue + fused dispatch stack produces an event stream byte-identical
+    to the retained reference stack (tuple-heap queue, pre-fusion
+    ``_handle`` chain)."""
     profiles = synthetic_fleet(16, seed=seed % 97, num_standby=2)
     table = _table_factory([dataclasses.replace(p) for p in profiles])
     sc = SCENARIOS[scenario_name](table, seed=seed, horizon_s=0.8)
@@ -192,55 +188,7 @@ else:
         _check_stack_equivalence(seed, scenario, max_batch, fair, gated)
 
 
-# ---- plan-reuse: key hygiene + replay identity ------------------------
-def _fleet_state(cache, *, backlogs=None, now=0.0, max_batch=32,
-                 down=()):
-    profiles = synthetic_fleet(6, seed=5)
-    for p in profiles:
-        if p.name in down:
-            p.available = False
-    table = ProfilingTable(POOL, profiles, seq_len=512)
-    return table, cache.snapshot(table, now=now, backlogs=backlogs,
-                                 max_batch=max_batch)
-
-
-def test_assembly_key_batched_tracks_read_backlogs():
-    """Batched assemblies read the available nodes' backlogs (the
-    quantized split's greedy tail placement), so the reuse key must
-    move when any *read* backlog moves — and must NOT move on backlog
-    changes the assembly never reads (unavailable nodes, or any node
-    when batching is off)."""
-    cache = SnapshotCache()
-    profiles = synthetic_fleet(6, seed=5)
-    down = profiles[2].name
-    read = profiles[0].name
-    for p in profiles:
-        if p.name == down:
-            p.available = False
-    table = ProfilingTable(POOL, profiles, seq_len=512)
-    levels = np.zeros(5, dtype=int)
-
-    def key(backlogs, max_batch=32):
-        state = cache.snapshot(table, backlogs=backlogs,
-                               max_batch=max_batch)
-        return _assembly_key(state, levels, 260)
-
-    base = key({read: 0.1, down: 0.7})
-    assert base is not None
-    # a read (available-node) backlog move must change the key
-    assert key({read: 0.2, down: 0.7}) != base
-    # an unavailable node's backlog is never read: key unchanged
-    assert key({read: 0.1, down: 9.9}) == base
-    # batching off: the split never reads backlogs at all
-    un = key({read: 0.1}, max_batch=1)
-    assert un == key({read: 5.0}, max_batch=1)
-    assert un != base                    # max_batch rides in plan_key
-    # hand-built snapshots (no perf_version) stay uncacheable
-    from repro.sched import ClusterState
-    bare = ClusterState.from_table(table, max_batch=32)
-    assert _assembly_key(bare, levels, 260) is None
-
-
+# ---- warm planners: memos leak nothing between snapshots -------------
 def _plan_fields(p):
     return (p.policy, p.dispatch.assignments, dict(p.node_service_s),
             dict(p.node_finish_s), p.exec_makespan_s, p.makespan_s,
@@ -254,61 +202,30 @@ def _plan_fields(p):
 @pytest.mark.parametrize("max_batch", [1, 32])
 def test_plan_replay_is_bit_identical_to_cold_assembly(policy_name,
                                                        max_batch):
-    """A cache hit's replayed Plan equals a cold build on the same
-    snapshot, field for field — including the recomputed finish times,
-    makespan, and feasibility under the *new* backlogs/perf_req."""
-    cache = SnapshotCache()
-    table, s1 = _fleet_state(cache, backlogs={}, max_batch=max_batch)
-    hi = float(np.asarray(table.perf)[0].sum())
-    warm = get_policy(policy_name)
-    req1 = InferenceRequest(rid=0, num_items=260, perf_req=0.4 * hi,
-                            acc_req=0.0)
-    warm.plan(s1, req1)
-    assert (warm._reuse.hits, warm._reuse.misses) == (0, 1)
-    # same profiling view + serving mask + levels outcome, but a moved
-    # clock and perf_req: replay must re-apply them exactly
-    s2 = cache.snapshot(table, now=3.5, backlogs={},
-                        max_batch=max_batch)
-    req2 = InferenceRequest(rid=1, num_items=260, perf_req=0.41 * hi,
-                            acc_req=0.0)
-    replayed = warm.plan(s2, req2)
-    assert warm._reuse.hits == 1
-    cold = get_policy(policy_name)     # fresh instance: empty cache
-    assert _plan_fields(replayed) == _plan_fields(cold.plan(s2, req2))
-
-
-def test_plan_cache_miss_on_read_backlog_hit_on_unread():
-    """End-to-end through ``plan()`` in batched mode: a backlog move on
-    an available node forces a cold re-assembly (miss), a move on an
-    unavailable node replays (hit)."""
+    """A warm policy instance plans exactly as a fresh one, field for
+    field: the selection memos it filled on one snapshot (the DP memo,
+    the oracle's enumeration cache) must not leak into the next one,
+    whether that moves the clock and perf_req, a backlog the batched
+    split reads (an available node's), or one it never reads (an
+    unavailable node's)."""
     cache = SnapshotCache()
     profiles = synthetic_fleet(6, seed=5)
     down, read = profiles[2].name, profiles[0].name
-    for p in profiles:
-        if p.name == down:
-            p.available = False
+    profiles[2].available = False
     table = ProfilingTable(POOL, profiles, seq_len=512)
     hi = float(np.asarray(table.perf)[0].sum())
-    req = InferenceRequest(rid=0, num_items=260, perf_req=0.4 * hi,
+    warm = get_policy(policy_name)
+    warm.plan(cache.snapshot(table, backlogs={read: 0.1, down: 0.7},
+                             max_batch=max_batch),
+              InferenceRequest(rid=0, num_items=260, perf_req=0.4 * hi,
+                               acc_req=0.0))
+    req = InferenceRequest(rid=1, num_items=260, perf_req=0.41 * hi,
                            acc_req=0.0)
-    pol = get_policy("proportional")
-
-    def plan(backlogs):
-        return pol.plan(cache.snapshot(table, backlogs=backlogs,
-                                       max_batch=32), req)
-
-    plan({read: 0.1, down: 0.7})
-    assert (pol._reuse.hits, pol._reuse.misses) == (0, 1)
-    plan({read: 0.3, down: 0.7})       # read backlog moved -> miss
-    assert (pol._reuse.hits, pol._reuse.misses) == (0, 2)
-    plan({read: 0.1, down: 4.2})       # unread backlog moved -> hit
-    assert (pol._reuse.hits, pol._reuse.misses) == (1, 2)
-    # disabling reuse (the reference stack's switch) stops both replay
-    # and counting new entries, and plans still come out cold-correct
-    pol._reuse.enabled = False
-    a = plan({read: 0.1, down: 4.2})
-    assert (pol._reuse.hits, pol._reuse.misses) == (1, 3)
-    b = get_policy("proportional").plan(
-        cache.snapshot(table, backlogs={read: 0.1, down: 4.2},
-                       max_batch=32), req)
-    assert _plan_fields(a) == _plan_fields(b)
+    for backlogs in ({read: 0.1, down: 0.7},     # clock + perf_req moved
+                     {read: 0.3, down: 0.7},     # read backlog moved
+                     {read: 0.1, down: 4.2}):    # unread backlog moved
+        state = cache.snapshot(table, now=3.5, backlogs=backlogs,
+                               max_batch=max_batch)
+        cold = get_policy(policy_name)           # fresh instance: no memo
+        assert _plan_fields(warm.plan(state, req)) == \
+            _plan_fields(cold.plan(state, req))

@@ -6,8 +6,8 @@ run-draining) and the oracle/DP per-plan residue; both keep a verbatim
 pre-optimization twin (``ShardedSimulator.run_reference``, the
 ``reference:`` planners), and these tests pin the optimized paths
 against the twins on seeded churn/straggler traffic and randomized
-profiling grids. The speedups in BENCH_8.json only count because the
-event streams and plans here are *identical*, not merely close.
+profiling grids: the event streams and plans must be *identical*, not
+merely close.
 
 The merge/DP properties run under hypothesis when it is installed;
 otherwise they fall back to a fixed seeded sweep over the same case

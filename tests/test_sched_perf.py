@@ -228,7 +228,7 @@ def test_fast_control_plane_matches_legacy(pool, scenario):
         sc = build_scenario(scenario, table, seed=3, horizon_s=8.0)
         policy = "reference:proportional" if legacy else "proportional"
         gn = GatewayNode(table, SimBackend(table, noise_std=0.05, seed=3),
-                         policy=policy, snapshot_caching=not legacy)
+                         policy=policy)
         return OnlineSimulator(gn, sc.arrivals, sc.faults,
                                scenario=sc.name, horizon_s=sc.horizon_s,
                                admission=AdmissionController(table),
@@ -238,10 +238,6 @@ def test_fast_control_plane_matches_legacy(pool, scenario):
     sf, sl = fast.summary(), legacy.summary()
     assert sf.keys() == sl.keys()
     for k in sf:
-        if k.startswith("plan_cache"):
-            # the reference policy plans cold by design; its counters
-            # are trivially zero while the fast stack's are not
-            continue
         assert sf[k] == pytest.approx(sl[k], abs=1e-9), k
     assert len(fast.log) == len(legacy.log)
     assert fast.n_events == legacy.n_events > 0
